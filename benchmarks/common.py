@@ -23,7 +23,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 from repro.core import topology
 from repro.data import synthetic
 from repro.fl import scenarios, simulator
+from repro.launch import compile_cache
 from repro.models import smallnets
+
+compile_cache.enable()
 
 # Harsher channel than the paper default so error effects are visible at
 # CPU-tractable scale (recorded in EXPERIMENTS.md): at 17 dBm the Table-II

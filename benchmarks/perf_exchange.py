@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def collective_schedules() -> None:
@@ -34,7 +34,7 @@ def collective_schedules() -> None:
     from repro.launch.dryrun import collective_bytes
 
     n = 16
-    mesh = jax.make_mesh((n,), ("clients",))
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:n]), ("clients",))
     m_params = 4_194_304          # 4M params (16 MB f32) per client
     seg_len = 1024
 

@@ -22,7 +22,6 @@ No orbax dependency — the container is offline.
 """
 from __future__ import annotations
 
-import inspect
 import json
 import os
 import tempfile
@@ -33,18 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from jax import shard_map
-except ImportError:                     # older jax (pre jax.shard_map)
-    from jax.experimental.shard_map import shard_map
-
-# Metric/state replication along the model axis is structural (DESIGN.md
-# §13), not something the rep checker can always prove — same shim as
-# repro.fl.scenarios.
-_SHARD_MAP_NO_CHECK = {
-    ("check_vma" if "check_vma" in inspect.signature(shard_map).parameters
-     else "check_rep"): False
-}
+from jax import shard_map
 
 Pytree = Any
 
@@ -254,13 +242,15 @@ def _chunk_programs(sim, mesh, closed: bool):
     st = {"w": P(None, sim.model_axis, None), "key": P()}
     if closed:
         st["sig"] = P()
+    # Metric/state replication along the model axis is structural (DESIGN.md
+    # §13), not something the replication checker can always prove.
     init = shard_map(
         sim.init_scan, mesh=mesh, in_specs=(P(),), out_specs=st,
-        **_SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )
     adv = shard_map(
         sim.advance_chunk, mesh=mesh, in_specs=(st, P(), P()),
-        out_specs=(st, P()), **_SHARD_MAP_NO_CHECK,
+        out_specs=(st, P()), check_vma=False,
     )
     return jax.jit(init), jax.jit(adv)
 

@@ -103,7 +103,6 @@ Public API
 from __future__ import annotations
 
 import dataclasses
-import inspect
 import itertools
 from collections import Counter, OrderedDict
 from typing import Any, Callable, Iterable, Sequence
@@ -113,16 +112,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:                                    # public API since jax 0.6
-    from jax import shard_map
-except ImportError:                     # older jax (pre jax.shard_map)
-    from jax.experimental.shard_map import shard_map
-
-# The replication-check kwarg was renamed check_rep -> check_vma.
-_SHARD_MAP_NO_CHECK = {
-    ("check_vma" if "check_vma" in inspect.signature(shard_map).parameters
-     else "check_rep"): False
-}
+from jax import shard_map
 
 from repro.core import compression, protocols, selection, topology
 from repro.data.synthetic import FederatedDataset
@@ -906,6 +896,10 @@ class ProgramCache:
         return {"programs": len(self._entries), "hits": self.hits,
                 "misses": self.misses, "evictions": self.evictions}
 
+    def compiled(self) -> list:
+        """The cached executables, least recently used first."""
+        return list(self._entries.values())
+
     def lookup(self, key, build: Callable[[], Any]):
         """The cached program for ``key``, compiling (and possibly
         evicting) on miss."""
@@ -1331,7 +1325,7 @@ class GridRunner:
                 # Grid axis: no collectives inside; model axis: metrics are
                 # replicated.  Skip the replication check (it rejects some
                 # primitives in the RNG/scan body).
-                **_SHARD_MAP_NO_CHECK,
+                check_vma=False,
             )
             return jax.jit(sharded, **self._donate).lower(args).compile()
 

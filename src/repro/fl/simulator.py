@@ -78,6 +78,7 @@ jit/vmap/shard_map-safe by construction (see tests/test_scenarios.py).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
 from typing import Any, Callable, NamedTuple
 
@@ -946,12 +947,12 @@ def build_sim(
         return metrics
 
     return SimPrograms(
-        round_step=round_step,
-        run_scenario=run_scenario,
+        round_step=_float32_matmuls(round_step),
+        run_scenario=_float32_matmuls(run_scenario),
         n_clients=n,
         n_rounds=n_rounds,
-        init_scan=init_scan,
-        advance_chunk=advance_chunk,
+        init_scan=_float32_matmuls(init_scan),
+        advance_chunk=_float32_matmuls(advance_chunk),
         n_chunks=n_chunks,
         eval_every=eval_every,
         model_shards=model_shards,
@@ -961,6 +962,26 @@ def build_sim(
         seg_len=seg_len,
         bits_per_value=bits_per_value,
     )
+
+
+def _float32_matmuls(fn: Callable) -> Callable:
+    """``fn`` traced with float32 matmuls and convolutions on every backend.
+
+    TPU's default precision multiplies float32 operands in one bf16 pass.
+    The simulated models end their early rounds with near-flat logits, so
+    at that precision test accuracy depends on how XLA batched the program:
+    a one-scenario program and a two-scenario program of the same scenario
+    measured 0.28 and 0.55 final accuracy on a v5e (FashionMNIST CNN, Fig. 9
+    network), with train losses equal to 1e-4.  A scenario's result must not
+    depend on what it was batched with (served vs `run_grid`, sharded vs
+    not), so the programs keep the float32 semantics of the CPU reference.
+    """
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("float32"):
+            return fn(*args, **kwargs)
+
+    return wrapped
 
 
 def donate_kwargs() -> dict:

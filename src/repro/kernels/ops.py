@@ -30,7 +30,7 @@ def interpret_default() -> bool:
 
 
 def ra_aggregate(w_seg, p, e, *, tx=None, mode: str = "ra_normalized",
-                 block_l: int = 8, interpret: bool | None = None):
+                 block_l: int = _ra.LANES, interpret: bool | None = None):
     """Fused R&A aggregation (paper eq. 6 / fused substitution baseline).
 
     w_seg: (N, L, K) or batched (B, N, L, K); p: (N,)/(B, N);
@@ -39,9 +39,8 @@ def ra_aggregate(w_seg, p, e, *, tx=None, mode: str = "ra_normalized",
     that composes the codec's per-segment transmit mask in-kernel.
     `jax.vmap` over a grid axis lowers onto the batched kernel.
     """
-    it = interpret_default() if interpret is None else interpret
     return _ra.ra_aggregate(w_seg, p, e, tx, mode=mode, block_l=block_l,
-                            interpret=it)
+                            interpret=interpret)
 
 
 def rwkv6_scan(r, k, v, w, u, *, chunk: int = 64, interpret: bool | None = None):

@@ -10,10 +10,9 @@ The paper's aggregation hot spot, for every receiver n and segment l:
 
 Naive jnp materializes the (N, N, L) coefficient tensor and an einsum over
 N x L x K in HBM.  On TPU the op is memory-bound (one pass over N copies of
-the model), so the kernel streams (L, K)-tiles of every sender's segments
+the model), so the kernel streams (BL, BK) tiles of every sender's segments
 through VMEM and fuses mask-weighting, reduction, and renormalization (or
-own-segment substitution) in a single pass — the receiver axis and an
-optional leading batch axis are grid dimensions, the segment axis is tiled.
+own-segment substitution) in a single pass.
 
 Batching: the public `ra_aggregate` accepts rank-3 ``w_seg`` (one scenario)
 or rank-4 (a leading batch axis, folded into the Pallas grid), and carries a
@@ -22,17 +21,47 @@ axis — including the vmap inside `scenarios.run_grid` / its `shard_map`
 wrapper — lowers onto the batched kernel instead of falling off it.  Nested
 vmaps flatten into the same single batch grid dimension.
 
-Tiling: block (BL segments x K values) per sender; K is the packet payload;
-BL chosen so N * BL * K * 4B fits comfortably in VMEM (~16 MB).  L is padded
-UP to a multiple of ``block_l`` (padded segments carry an all-zero mask and
-are sliced off the output) — never the block shrunk to a divisor, which for
-prime L (e.g. L=1181) would degenerate to BL=1 and serialize the segment
-axis.
+Layout (Mosaic's rule: a block's last two dims are multiples of (8, 128) or
+equal to the array's):
 
-The mask ``e`` may arrive as bool_/uint8 (the packed on-the-wire form —
-see `errors.sample_success`) or float32; it is cast to float32 exactly once
-at the kernel edge, so kernel semantics match the float32 reference
-bit-for-bit in value.
+  * ``w``/``own``/``out``: (B, N, L, K) blocks (1, N | 1, BL, BK) — segments
+    on sublanes, packet values on lanes.
+  * ``e``: receiver-major (B, N_recv, N_send, L) blocks (1, 1, N, BL) and
+    ``tx`` (B, N, L) blocks (1, N, BL) — the SEGMENT axis on lanes, the full
+    sender axis on sublanes.  The mask stays packed in HBM: ``bool_`` and
+    ``uint8`` are cast to ``int8`` (same bytes) at the kernel edge, since
+    Mosaic casts neither to float32; float masks enter as they are.  Each
+    step casts its (N, BL) block to float32 once and transposes it in VMEM,
+    so each sender's coefficients become a (BL, 1) column that broadcasts
+    over the packet lanes.
+  * ``p``: (B, 1, N) float32 in SMEM, one (1, 1, N) block per scenario,
+    read as scalars.
+
+Grid (B, L/BL, K/BK, N_recv), receiver innermost: the (N, BL, BK) sender
+block keeps its block index across the receiver sweep, so it is copied
+from HBM once per (scenario, tile) rather than once per receiver.
+
+Tiling: BL is a multiple of the 128-lane width (``block_l``, default 128),
+or all of L when L <= block_l (a full-dim block needs no padding).  L is
+padded UP to a multiple of BL (padded segments carry an all-zero mask and
+are sliced off the output) — never the block shrunk to a divisor, which for
+prime L (e.g. L=1181) would degenerate the segment axis.  BK is the largest
+multiple-of-128 divisor of K whose sender block fits ``_W_BLOCK_BYTES``
+(4 MiB), else all of K.
+
+VMEM budget on TPU v5e (16 MiB scoped default of 128 MiB physical), f32,
+N = 10, BL = 128, every buffer double-buffered by the pipeline:
+
+  ============================  =================  ==================
+  shape (B, N, L, K)            BK                 VMEM per step
+  ============================  =================  ==================
+  (8, 10, 412, 1024)  CNN       512 (w 2.5 MiB)    6.5 MiB
+  (4, 10, 2225, 512)  NWP       512 (w 2.5 MiB)    6.5 MiB
+  ============================  =================  ==================
+
+(2 x w + 2 x out + 2 x own + two (BL, BK) f32 temporaries; the mask and
+tx blocks are a few KiB.)  ``vmem_limit_bytes`` is 5/4 of the estimate,
+at least the scoped default: many senders raise it, up to 100 MiB.
 """
 from __future__ import annotations
 
@@ -41,105 +70,103 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 MODES = ("ra_normalized", "substitution")
 
+LANES = 128
+_W_BLOCK_BYTES = 4 * 2**20         # per sender-block buffer
+_SCOPED_VMEM_BYTES = 16 * 2**20    # v5e default scoped VMEM limit
+_MAX_VMEM_BYTES = 100 * 2**20      # below v5e's 128 MiB physical VMEM
 
-def _ra_kernel(p_ref, e_ref, w_ref, out_ref):
-    """One grid step of adaptive normalization: (batch, receiver, seg block).
 
-    Block views:
-      p_ref:   (1, N)           aggregation weights (replicated per step)
-      e_ref:   (1, 1, N, BL)    success-mask column for THIS receiver
-      w_ref:   (1, N, BL, K)    sender segments for this segment block
-      out_ref: (1, 1, BL, K)    aggregated output
+def _kernel(*refs, mode, has_tx):
+    """One grid step: (scenario, segment tile, value tile, receiver).
+
+    Block views (N senders, BL segments, BK values):
+      p_ref:   (1, 1, N)      SMEM aggregation weights of this scenario
+      e_ref:   (1, 1, N, BL)  success mask column of THIS receiver
+      tx_ref:  (1, N, BL)     optional per-segment transmit mask
+      w_ref:   (1, N, BL, BK) sender segments
+      own_ref: (1, 1, BL, BK) the receiver's own segments (substitution)
+      out_ref: (1, 1, BL, BK)
     """
-    p = p_ref[0]                                      # (N,)
-    e = e_ref[0, 0].astype(jnp.float32)               # (N, BL)
-    w = w_ref[0].astype(jnp.float32)                  # (N, BL, K)
-    coeff = p[:, None] * e                            # (N, BL)
-    denom = jnp.maximum(jnp.sum(coeff, axis=0), 1e-12)  # (BL,)
-    num = jnp.sum(coeff[:, :, None] * w, axis=0)      # (BL, K)
-    out_ref[0, 0] = (num / denom[:, None]).astype(out_ref.dtype)
+    refs = list(refs)
+    p_ref, e_ref = refs.pop(0), refs.pop(0)
+    tx_ref = refs.pop(0) if has_tx else None
+    w_ref = refs.pop(0)
+    own_ref = refs.pop(0) if mode == "substitution" else None
+    (out_ref,) = refs
+    n = w_ref.shape[1]
+
+    e = e_ref[0, 0].astype(jnp.float32)                  # (N, BL)
+    if has_tx:
+        # Pruned sender segments (tx == 0) drop out of the delivered set;
+        # the receiver's own row is restored to 1 (its own segments never
+        # cross the air) — `aggregation.apply_transmit_mask` per block, so
+        # the packed (N, L) transmit mask is read instead of a pre-composed
+        # (N, N, L) one.
+        sender = jax.lax.broadcasted_iota(jnp.int32, e.shape, 0)
+        e = jnp.where(sender == pl.program_id(3), 1.0,
+                      e * tx_ref[0].astype(jnp.float32))
+    e_t = e.T                                            # (BL, N)
+
+    num = jnp.zeros(out_ref.shape[2:], jnp.float32)      # (BL, BK)
+    den = jnp.zeros((e_t.shape[0], 1), jnp.float32)      # (BL, 1)
+    p_sum = jnp.float32(0.0)
+    for m in range(n):
+        coeff = p_ref[0, 0, m] * e_t[:, m:m + 1]            # (BL, 1)
+        num = num + coeff * w_ref[0, m].astype(jnp.float32)
+        den = den + coeff
+        p_sum = p_sum + p_ref[0, 0, m]
+    if mode == "substitution":
+        # Lost-sender mass sum_m p_m (1 - e) = sum(p) - sum(coeff): no
+        # (1 - e) tensor is built.
+        out = num + (p_sum - den) * own_ref[0, 0].astype(jnp.float32)
+    else:
+        out = num / jnp.maximum(den, 1e-12)
+    out_ref[0, 0] = out.astype(out_ref.dtype)
 
 
-def _ra_kernel_sub(p_ref, e_ref, w_ref, own_ref, out_ref):
-    """One grid step of fused model substitution.
-
-    Extra block view:
-      own_ref: (1, 1, BL, K)    the RECEIVER's own segments for this block
-    The lost-sender mass sum_m p_m (1 - e) folds to sum(p) - sum(coeff), so
-    no (1 - e) tensor is ever built.
-    """
-    p = p_ref[0]
-    e = e_ref[0, 0].astype(jnp.float32)
-    w = w_ref[0].astype(jnp.float32)
-    own = own_ref[0, 0].astype(jnp.float32)           # (BL, K)
-    coeff = p[:, None] * e
-    num = jnp.sum(coeff[:, :, None] * w, axis=0)
-    miss = jnp.sum(p) - jnp.sum(coeff, axis=0)        # (BL,)
-    out_ref[0, 0] = (num + miss[:, None] * own).astype(out_ref.dtype)
-
-
-def _tx_compose(e, tx):
-    """In-VMEM transmit-mask composition for one receiver's (N, BL) block.
-
-    Pruned sender segments (tx == 0) drop out of the delivered set; the
-    receiver's own row is restored to 1 (its own segments never cross the
-    air).  Mirrors `aggregation.apply_transmit_mask` per block — done here
-    so the sparsity-aware path consumes the compact PACKED (N, L) transmit
-    mask straight from HBM instead of a pre-composed (N, N, L) tensor (an
-    extra full success-mask's worth of HBM traffic on a memory-bound op).
-    The receiver index is grid dimension 1; TPU needs the >= 2-D
-    broadcasted_iota form.
-    """
-    r = pl.program_id(1)
-    sender = jax.lax.broadcasted_iota(jnp.int32, e.shape, 0)
-    return jnp.where(sender == r, 1.0, e * tx)
+def _tiles(n, l, k, itemsize, block_l):
+    """(BL, padded L, BK, VMEM bytes per step) — see the module docstring."""
+    if block_l < 1 or block_l % LANES:
+        raise ValueError(
+            f"block_l must be a positive multiple of {LANES} (the segment "
+            f"axis is on lanes), got {block_l}"
+        )
+    bl = l if l <= block_l else block_l
+    lp = -(-l // bl) * bl
+    bk = k
+    if k % LANES == 0:
+        fits = [d for d in range(LANES, k + 1, LANES)
+                if k % d == 0 and n * bl * d * itemsize <= _W_BLOCK_BYTES]
+        bk = fits[-1] if fits else LANES
+    tile = bl * bk
+    vmem = 2 * (n * tile * itemsize) + 4 * tile * itemsize + 2 * tile * 4
+    return bl, lp, bk, vmem
 
 
-def _ra_kernel_tx(p_ref, e_ref, tx_ref, w_ref, out_ref):
-    """Sparsity-aware adaptive normalization: extra tx_ref (1, N, BL)."""
-    p = p_ref[0]
-    e = e_ref[0, 0].astype(jnp.float32)
-    tx = tx_ref[0].astype(jnp.float32)                # (N, BL)
-    e = _tx_compose(e, tx)
-    w = w_ref[0].astype(jnp.float32)
-    coeff = p[:, None] * e
-    denom = jnp.maximum(jnp.sum(coeff, axis=0), 1e-12)
-    num = jnp.sum(coeff[:, :, None] * w, axis=0)
-    out_ref[0, 0] = (num / denom[:, None]).astype(out_ref.dtype)
-
-
-def _ra_kernel_sub_tx(p_ref, e_ref, tx_ref, w_ref, own_ref, out_ref):
-    """Sparsity-aware substitution: pruned + lost mass folds to own block."""
-    p = p_ref[0]
-    e = e_ref[0, 0].astype(jnp.float32)
-    tx = tx_ref[0].astype(jnp.float32)
-    e = _tx_compose(e, tx)
-    w = w_ref[0].astype(jnp.float32)
-    own = own_ref[0, 0].astype(jnp.float32)
-    coeff = p[:, None] * e
-    num = jnp.sum(coeff[:, :, None] * w, axis=0)
-    miss = jnp.sum(p) - jnp.sum(coeff, axis=0)
-    out_ref[0, 0] = (num + miss[:, None] * own).astype(out_ref.dtype)
+def _packed(mask):
+    """A 0/1 mask as the kernel reads it: float masks as-is, else int8."""
+    return mask if jnp.issubdtype(mask.dtype, jnp.floating) else \
+        mask.astype(jnp.int8)
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "block_l", "interpret"))
 def _ra_call(w_seg, p, e, tx=None, *, mode, block_l, interpret):
     """The batched pallas_call: w_seg (B, N, L, K), p (B, N), e (B, N, N, L).
 
-    The leading batch axis is a grid dimension — grid (B, N, ceil(L/BL)).
     ``tx`` (optional, (B, N, L) packed) selects the sparsity-aware kernel
     variant; its presence is a static (trace-level) choice.
     """
     b, n, l, k = w_seg.shape
-    bl = min(block_l, l)
-    lp = -(-l // bl) * bl
-    # e arranged receiver-major for clean blocking: (B, receiver, sender, L).
-    # The mask keeps its packed dtype through HBM; each kernel step casts
-    # only its (N, BL) block to float32 in VMEM.
-    e_rm = jnp.swapaxes(e, 1, 2)
+    bl, lp, bk, vmem = _tiles(n, l, k, w_seg.dtype.itemsize, block_l)
+    # Receiver-major mask (B, receiver, sender, L); packed masks travel as
+    # int8 (Mosaic casts neither bool_ nor uint8 refs to float32).
+    e_rm = _packed(jnp.swapaxes(e, 1, 2))
+    if tx is not None:
+        tx = _packed(tx)
     if lp != l:
         # Pad L UP to a block multiple (zero mask + zero segments: the padded
         # tail is sliced off below) instead of shrinking BL to a divisor.
@@ -147,39 +174,34 @@ def _ra_call(w_seg, p, e, tx=None, *, mode, block_l, interpret):
         e_rm = jnp.pad(e_rm, ((0, 0), (0, 0), (0, 0), (0, lp - l)))
         if tx is not None:
             tx = jnp.pad(tx, ((0, 0), (0, 0), (0, lp - l)))
-    grid = (b, n, lp // bl)
-    p2 = p.astype(jnp.float32)
 
     in_specs = [
-        pl.BlockSpec((1, n), lambda bi, r, s: (bi, 0)),             # p
-        pl.BlockSpec((1, 1, n, bl), lambda bi, r, s: (bi, r, 0, s)),  # e
+        pl.BlockSpec((1, 1, n), lambda bi, s, c, r: (bi, 0, 0),
+                     memory_space=pltpu.SMEM),                      # p
+        pl.BlockSpec((1, 1, n, bl), lambda bi, s, c, r: (bi, r, 0, s)),  # e
     ]
-    args = [p2, e_rm]
+    args = [p.astype(jnp.float32)[:, None, :], e_rm]
     if tx is not None:
-        in_specs.append(
-            pl.BlockSpec((1, n, bl), lambda bi, r, s: (bi, 0, s))   # tx
-        )
+        in_specs.append(pl.BlockSpec((1, n, bl),
+                                     lambda bi, s, c, r: (bi, 0, s)))
         args.append(tx)
-    in_specs.append(
-        pl.BlockSpec((1, n, bl, k), lambda bi, r, s: (bi, 0, s, 0))   # w
-    )
+    in_specs.append(pl.BlockSpec((1, n, bl, bk),
+                                 lambda bi, s, c, r: (bi, 0, s, c)))  # w
     args.append(w_seg)
+    own_spec = pl.BlockSpec((1, 1, bl, bk), lambda bi, s, c, r: (bi, r, s, c))
     if mode == "substitution":
-        kernel = _ra_kernel_sub if tx is None else _ra_kernel_sub_tx
-        # The receiver's own segment block (same array, receiver-indexed).
-        in_specs.append(
-            pl.BlockSpec((1, 1, bl, k), lambda bi, r, s: (bi, r, s, 0))
-        )
+        in_specs.append(own_spec)
         args.append(w_seg)
-    else:
-        kernel = _ra_kernel if tx is None else _ra_kernel_tx
 
     out = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_kernel, mode=mode, has_tx=tx is not None),
+        grid=(b, lp // bl, k // bk, n),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, bl, k), lambda bi, r, s: (bi, r, s, 0)),
+        out_specs=own_spec,
         out_shape=jax.ShapeDtypeStruct((b, n, lp, k), w_seg.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=min(max(vmem * 5 // 4, _SCOPED_VMEM_BYTES),
+                                 _MAX_VMEM_BYTES)),
         interpret=interpret,
     )(*args)
     return out[:, :, :l] if lp != l else out
@@ -289,8 +311,8 @@ def ra_aggregate(
     tx: jnp.ndarray | None = None,
     *,
     mode: str = "ra_normalized",
-    block_l: int = 8,
-    interpret: bool = True,
+    block_l: int = LANES,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Fused R&A aggregation. See ref.ra_aggregate_ref for semantics.
 
@@ -306,14 +328,19 @@ def ra_aggregate(
              in VMEM instead of pre-materializing a composed (N, N, L)
              success mask in HBM.
       mode: "ra_normalized" (eq. 6) or "substitution" (fused baseline [12]).
-      block_l: segments per VMEM tile (L pads up to a multiple).
-      interpret: run in Pallas interpret mode (CPU validation; TPU: False).
+      block_l: segments per VMEM tile, a multiple of 128 (L pads up to a
+             multiple; L <= block_l is one full-L tile).
+      interpret: run in Pallas interpret mode; None resolves through
+             `repro.kernels.ops.interpret_default` (native only on TPU).
 
     `jax.vmap` over a leading axis of any argument lowers onto the batched
     kernel (custom_vmap rule) — the grid engine's vmap/shard_map included.
     """
     if mode not in MODES:
         raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
+    if interpret is None:
+        from repro.kernels import ops   # ops imports this module
+        interpret = ops.interpret_default()
     if w_seg.ndim == 4:
         b, n, l, _ = w_seg.shape
         if p.ndim == 1:   # shared weights across the batch

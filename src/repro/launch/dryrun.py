@@ -377,9 +377,10 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
     t_cost = time.time() - t0 - t_full
 
     flops, bytes_accessed, coll_total = costs["flops"], costs["bytes"], costs["coll"]
-    compute_s = flops / meshlib.PEAK_FLOPS_BF16
-    memory_s = bytes_accessed / meshlib.HBM_BW
-    collective_s = coll_total / meshlib.ICI_BW
+    peaks = meshlib.chip_peaks(meshlib.PRODUCTION_KIND)
+    compute_s = flops / peaks["flops_bf16"]
+    memory_s = bytes_accessed / peaks["hbm_bw"]
+    collective_s = coll_total / peaks["ici_bw"]
 
     n_tokens = shape.global_batch * (shape.seq_len if shape.kind != "decode" else 1)
     mf = model_flops(cfg, n_tokens, train=shape.kind == "train")

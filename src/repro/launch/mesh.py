@@ -25,16 +25,38 @@ from typing import Sequence
 import jax
 import numpy as np
 
-# TPU v5e hardware constants (per chip) used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12       # FLOP/s
-HBM_BW = 819e9                 # bytes/s
-ICI_BW = 50e9                  # bytes/s per link
+# Published per-chip peaks, keyed by `jax.Device.device_kind`.  Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s interconnect = 4 links x 50 GB/s).
+CHIP_PEAKS = {
+    "TPU v5 lite": {
+        "flops_bf16": 197e12,   # FLOP/s
+        "hbm_bw": 819e9,        # bytes/s
+        "ici_bw": 50e9,         # bytes/s per link
+    },
+}
+
+# The chip the production dry-run meshes describe.
+PRODUCTION_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> dict[str, float]:
+    """Peaks of one chip of ``device_kind``; an unknown kind is an error."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(CHIP_PEAKS)}"
+        ) from None
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def data_axes(*, multi_pod: bool = False):

@@ -82,6 +82,7 @@ import numpy as np
 
 from repro.data.synthetic import FederatedDataset
 from repro.fl import scenarios, simulator
+from repro.launch import compile_cache
 from repro.launch import tracker as launch_tracker
 
 Pytree = object
@@ -982,6 +983,7 @@ def main() -> None:
                          "(0 = single-device vmap)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     data, nets, init, apply_fn = _demo_setup(args.clients, 20, args.seed)
     cfg = simulator.SimConfig(n_rounds=args.rounds, local_epochs=2,

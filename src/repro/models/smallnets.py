@@ -35,12 +35,50 @@ def _fc_init(key, din, dout, dtype=jnp.float32):
     }
 
 
-def conv2d(x, w, stride=1):
-    """x: (B, H, W, C); w: (kh, kw, cin, cout); SAME padding."""
+def _conv(x, w, stride):
     return jax.lax.conv_general_dilated(
         x, w, (stride, stride), "SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
     )
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def conv2d(x, w, stride=1):
+    """x: (B, H, W, C); w: (kh, kw, cin, cout); SAME padding.
+
+    The weight gradient is one contraction per kernel tap instead of JAX's
+    transposed convolution: under a `shard_map` (the sharded scenario grid)
+    JAX cannot batch that convolution over a second vmap (scenarios over
+    clients) and raises NotImplementedError.
+    """
+    return _conv(x, w, stride)
+
+
+def _conv2d_fwd(x, w, stride):
+    return _conv(x, w, stride), (x, w)
+
+
+def _conv2d_bwd(stride, res, g):
+    x, w = res
+    _, vjp_x = jax.vjp(lambda x_: _conv(x_, w, stride), x)
+    kh, kw = w.shape[:2]
+    pads = jax.lax.padtype_to_pads(x.shape[1:3], (kh, kw), (stride, stride),
+                                   "SAME")
+    xp = jnp.pad(x, ((0, 0), *pads, (0, 0)))
+    ho, wo = g.shape[1:3]
+    dw = jnp.stack([
+        jnp.stack([
+            jnp.einsum("bhwc,bhwo->co",
+                       xp[:, i:i + (ho - 1) * stride + 1:stride,
+                          j:j + (wo - 1) * stride + 1:stride], g)
+            for j in range(kw)
+        ])
+        for i in range(kh)
+    ])
+    return vjp_x(g)[0], dw.astype(w.dtype)
+
+
+conv2d.defvjp(_conv2d_fwd, _conv2d_bwd)
 
 
 def avgpool(x, k=2):

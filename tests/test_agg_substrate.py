@@ -54,10 +54,10 @@ def test_pallas_matches_ref_both_modes(mode, n, l, k, dtype):
 def test_pallas_prime_l_keeps_block_size(mode):
     """Prime L (coprime with every block_l > 1) pads UP to a block multiple
     instead of degenerating to BL=1; results still match the oracle."""
-    n, l, k = 4, 37, 32
+    n, l, k = 4, 131, 32
     w, p, e = _setup(9, n, l, k)
     want = REFS[mode](w, p, e.astype(jnp.float32))
-    for bl in (1, 4, 8, 16, 64):   # 64 > L: single padded block
+    for bl in (128, 256):   # two padded tiles; 256 > L: one full-L tile
         got = ops.ra_aggregate(w, p, e, mode=mode, block_l=bl)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5, err_msg=f"block_l={bl}")

@@ -263,3 +263,51 @@ def test_sim_registry_all_models():
         registry.sim_model("not-a-model")
     with pytest.raises(ValueError, match="decoder-only"):
         registry.nwp_cfg("whisper_base")
+
+
+@pytest.mark.parametrize("hw,cin,cout,k,stride", [
+    (7, 2, 3, 3, 1),    # the CNN's 3x3 SAME convs
+    (8, 3, 4, 3, 2),    # ResNet's strided stage entry
+    (9, 2, 5, 1, 2),    # ResNet's 1x1 strided projection
+])
+def test_conv2d_vjp_matches_native(hw, cin, cout, k, stride):
+    """The per-tap weight gradient equals JAX's transposed convolution."""
+    from repro.models import smallnets
+
+    kx, kw = jax.random.split(jax.random.PRNGKey(0))
+    x = jax.random.normal(kx, (2, hw, hw, cin))
+    w = jax.random.normal(kw, (k, k, cin, cout))
+
+    def loss(conv):
+        return lambda x_, w_: jnp.sum(jnp.sin(conv(x_, w_, stride)))
+
+    got = jax.grad(loss(smallnets.conv2d), (0, 1))(x, w)
+    want = jax.grad(loss(smallnets._conv), (0, 1))(x, w)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=1e-5)
+
+
+def test_conv2d_grad_batches_under_shard_map():
+    """Scenarios x clients vmap of a conv weight gradient inside shard_map
+    (the sharded scenario grid): JAX's own transposed convolution raises
+    NotImplementedError there."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.models import smallnets
+
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("grid",))
+    x = jax.random.normal(jax.random.PRNGKey(1), (3, 2, 5, 5, 1))
+    w = jax.random.normal(jax.random.PRNGKey(2), (2, 3, 3, 3, 1, 2))
+
+    def grid(conv):
+        def loss(w_, x_):
+            return jnp.sum(conv(x_, w_, 1) ** 2)
+        return lambda ws: jax.vmap(
+            lambda wg: jax.vmap(jax.grad(loss))(wg, x))(ws)
+
+    sharded = jax.shard_map(grid(smallnets.conv2d), mesh=mesh,
+                            in_specs=P("grid"), out_specs=P("grid"),
+                            check_vma=False)
+    got = jax.jit(sharded)(w)
+    want = jax.jit(grid(smallnets._conv))(w)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5)

@@ -444,11 +444,11 @@ def test_dfl_step_participation_and_selection():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import dfl_step, protocols, selection
 
         n = 8
-        mesh = jax.make_mesh((n,), ("clients",))
+        mesh = jax.sharding.Mesh(np.asarray(jax.devices()), ("clients",))
         key = jax.random.PRNGKey(0)
         params = {"w": jax.random.normal(key, (n, 4, 6)),
                   "b": jax.random.normal(key, (n, 6))}
