@@ -834,6 +834,20 @@ def _bucket_target(g: int, pad_to) -> int:
     return -(-g // top) * top
 
 
+_PROTOCOL_NAMES = {v: k for k, v in PROTOCOL_IDS.items()}
+_MODE_NAMES = {v: k for k, v in MODE_IDS.items()}
+
+
+def _group_label(grid: ScenarioGrid, idx: list[int]) -> str:
+    """``<protocol>+<mode>`` of a dispatch group's rows, or ``mixed``."""
+    sc = grid.scenarios
+    pairs = {(int(sc.protocol_id[i]), int(sc.mode_id[i])) for i in idx}
+    if len(pairs) != 1:
+        return "mixed"
+    ((pid, mid),) = pairs
+    return f"{_PROTOCOL_NAMES[pid]}+{_MODE_NAMES[mid]}"
+
+
 def _stack_rows(*leaves):
     """Stack per-row metric leaves back into the grid axis.
 
@@ -1075,7 +1089,9 @@ class GridRunner:
         int (first k devices), or None for the single-device vmap path.
         Overridable per call.
       tracker: metrics sink (`repro.launch.tracker.Tracker`) for cache
-        hit/miss/evict counters and batch fill ratios; defaults to the
+        hit/miss/evict counters, batch fill ratios and the ``grid/*``
+        spans of each `run` (``run``, ``validate``, per dispatch group
+        ``prepare`` and ``dispatch``, ``collect``); defaults to the
         no-op NullTracker.
       max_cached_programs: LRU bound on the compiled-program cache
         (DESIGN.md §11).  None = unbounded — fine for one-shot figure
@@ -1112,6 +1128,7 @@ class GridRunner:
         self._sims: dict[int, simulator.SimPrograms] = {1: self.sim}
         self.devices = devices
         self.tracker = tracker or launch_tracker.NullTracker()
+        self._calls = 0         # `run` calls so far: the spans' ``run`` id
         self._seg_len = cfg.seg_len
         # Bounded LRU of AOT-compiled executables, keyed by (kind, hoist
         # signature, mesh, input avals) — see ProgramCache.
@@ -1189,40 +1206,53 @@ class GridRunner:
         ``validate=False`` skips admission validation (`validate_grid`)
         for callers that already validated at submission time.
         """
-        mesh = _resolve_grid_mesh(
-            self.devices if devices is _INHERIT else devices, sharding
-        )
-        # Surface PER-packet vs codec-segment mismatches on the grid path
-        # too (one-time warning; see simulator.check_packet_len).  The
-        # per-value bit width follows the bound model's state dtype.
-        for bits in getattr(grid, "packet_len_bits", ()):
-            simulator.check_packet_len(
-                bits, self._seg_len, bits_per_value=self.sim.bits_per_value
+        self._calls += 1
+        run = self._calls
+        with self.tracker.span("grid/run", run=run):
+            mesh = _resolve_grid_mesh(
+                self.devices if devices is _INHERIT else devices, sharding
             )
-        if validate:
-            self.validate(grid)
-        g = len(grid)
-        index_groups = self._index_groups(grid, group_by_protocol)
+            with self.tracker.span("grid/validate", run=run):
+                # Surface PER-packet vs codec-segment mismatches on the
+                # grid path too (one-time warning; see
+                # simulator.check_packet_len).  The per-value bit width
+                # follows the bound model's state dtype.
+                for bits in getattr(grid, "packet_len_bits", ()):
+                    simulator.check_packet_len(
+                        bits, self._seg_len,
+                        bits_per_value=self.sim.bits_per_value,
+                    )
+                if validate:
+                    self.validate(grid)
+                index_groups = self._index_groups(grid, group_by_protocol)
 
-        rows: list[dict | None] = [None] * g
-        for idx in index_groups:
-            sub = jax.tree.map(
-                lambda leaf: leaf[np.asarray(idx)], grid.scenarios
-            )
-            target = _bucket_target(len(idx), pad_to)
-            if target != len(idx):
-                sub = _pad_scenario_batch(sub, target)
-            self.tracker.observe("grid/batch_fill", len(idx) / target)
-            if mesh is None:
-                program, args = self._program_vmap(sub)
-            else:
-                program, args = self._program_sharded(sub, mesh)
-            metrics = program(args)
-            # Unpad: filler rows (j >= len(idx)) are simply never read.
-            for j, i in enumerate(idx):
-                rows[i] = jax.tree.map(lambda leaf: leaf[j], metrics)
-        stacked = jax.tree.map(_stack_rows, *rows)
-        return _metrics_to_grid_result(stacked, grid.labels)
+            rows: list[dict | None] = [None] * len(grid)
+            for idx in index_groups:
+                target = _bucket_target(len(idx), pad_to)
+                meta = {"run": run, "group": _group_label(grid, idx),
+                        "batch": target}
+                with self.tracker.span("grid/prepare", **meta):
+                    sub = jax.tree.map(
+                        lambda leaf: leaf[np.asarray(idx)], grid.scenarios
+                    )
+                    if target != len(idx):
+                        sub = _pad_scenario_batch(sub, target)
+                    self.tracker.observe("grid/batch_fill", len(idx) / target)
+                    if mesh is None:
+                        program, args = self._program_vmap(sub)
+                    else:
+                        program, args = self._program_sharded(sub, mesh)
+                with self.tracker.span("grid/dispatch", **meta):
+                    metrics = program(args)
+                    # Unpad: filler rows (j >= len(idx)) are never read.
+                    # The row slices queue on the device behind this
+                    # program, while the next group's program runs.
+                    for j, i in enumerate(idx):
+                        rows[i] = jax.tree.map(lambda leaf: leaf[j], metrics)
+
+            with self.tracker.span("grid/collect", run=run):
+                stacked = jax.tree.map(_stack_rows, *rows)
+                return _metrics_to_grid_result(stacked, grid.labels)
 
     def warmup(self, grid: ScenarioGrid, *,
                group_by_protocol: bool = True,

@@ -70,6 +70,7 @@ Public API
   SimPrograms.run_scenario  scenario -> metrics dict (scanned n_rounds)
   run / simulate            scalar one-scenario entry point -> SimResult
   metrics_to_result         metrics dict -> SimResult
+  ROUND_SCOPES              the round's phases as `jax.named_scope`s
 
 Purity contract: `round_step` and `run_scenario` are side-effect free
 functions of their arguments plus the statics bound by `build_sim` —
@@ -101,6 +102,14 @@ MODEL_AXIS = "model"
 # codec="none" draws the same channel randomness as no codec at all —
 # load-bearing for the neutral codec's bitwise guarantee (DESIGN.md §15).
 _CODEC_KEY_TAG = 0x434F4445  # "CODE"
+
+# The round's phases as `jax.named_scope`s: every op of local training,
+# of the exchange (codec, mask draw, aggregation, bias) and of metric
+# evaluation carries its phase in its ``op_name`` metadata, so a device
+# trace splits a round by phase.  Scopes add metadata only: the compiled
+# instructions are the same with or without them.
+ROUND_SCOPES = ("local_train", "exchange", "evaluate")
+_TRAIN_SCOPE, _EXCHANGE_SCOPE, _EVAL_SCOPE = ROUND_SCOPES
 
 
 class PacketLengthMismatchWarning(UserWarning):
@@ -740,36 +749,39 @@ def build_sim(
         budget policy's per-client waterfill (`_advance_closed`).
         """
         w_full = _full_rows(w_loc)
-        trained = local_train(w_full, scenario.lr, scenario.local_epochs)
-        if part is not None:
-            trained = jnp.where(part[:, None, None] > 0, trained, w_full)
-        tx_mask = None
-        w_send = trained
-        if scenario.codec_id is not None:
-            ratio = (scenario.compress_ratio if ratio_override is None
-                     else ratio_override)
-            w_send, tx_full = compression.encode(
-                scenario.codec_id, trained, ratio,
-                jax.random.fold_in(rng, _CODEC_KEY_TAG),
-                n_real=s_total, dtype_bits=bits_per_value,
+        with jax.named_scope(_TRAIN_SCOPE):
+            trained = local_train(w_full, scenario.lr, scenario.local_epochs)
+            if part is not None:
+                trained = jnp.where(part[:, None, None] > 0, trained, w_full)
+        with jax.named_scope(_EXCHANGE_SCOPE):
+            tx_mask = None
+            w_send = trained
+            if scenario.codec_id is not None:
+                ratio = (scenario.compress_ratio if ratio_override is None
+                         else ratio_override)
+                w_send, tx_full = compression.encode(
+                    scenario.codec_id, trained, ratio,
+                    jax.random.fold_in(rng, _CODEC_KEY_TAG),
+                    n_real=s_total, dtype_bits=bits_per_value,
+                )
+                tx_mask = tx_full[:, :s_total]
+            w_ex = _local_window(w_send)
+            w_raw = (None if scenario.codec_id is None
+                     else _local_window(trained))
+            new_loc, _e, bias = protocols.dispatch_round_seg(
+                w_ex, p, scenario.rho, scenario.link_eps, rng,
+                scenario.protocol_id, scenario.mode_id, scenario.aggregator,
+                n_mixes=aayg_mixes, participation=part,
+                tx_mask=tx_mask, w_raw=w_raw,
+                agg_impl=agg_impl, track_bias=track_bias,
+                seg_total=None if model_shards == 1 else s_total,
+                seg_start=_seg_start(),
             )
-            tx_mask = tx_full[:, :s_total]
-        w_ex = _local_window(w_send)
-        w_raw = None if scenario.codec_id is None else _local_window(trained)
-        new_loc, _e, bias = protocols.dispatch_round_seg(
-            w_ex, p, scenario.rho, scenario.link_eps, rng,
-            scenario.protocol_id, scenario.mode_id, scenario.aggregator,
-            n_mixes=aayg_mixes, participation=part,
-            tx_mask=tx_mask, w_raw=w_raw,
-            agg_impl=agg_impl, track_bias=track_bias,
-            seg_total=None if model_shards == 1 else s_total,
-            seg_start=_seg_start(),
-        )
-        if scenario.codec_id is not None and part is not None:
-            # dispatch restores sampled-out receivers to its exchange INPUT
-            # (the encoded w_ex); a client that sat the round out must keep
-            # its unencoded state instead.
-            new_loc = jnp.where(part[:, None, None] > 0, new_loc, w_raw)
+            if scenario.codec_id is not None and part is not None:
+                # dispatch restores sampled-out receivers to its exchange
+                # INPUT (the encoded w_ex); a client that sat the round out
+                # must keep its unencoded state instead.
+                new_loc = jnp.where(part[:, None, None] > 0, new_loc, w_raw)
         return new_loc, trained, w_full, bias
 
     def _advance(w_loc: jnp.ndarray, rng: jax.Array, scenario: Scenario):
@@ -871,11 +883,12 @@ def build_sim(
             part = part[:n]
         w_seg, spec, mp = protocols._to_segments(state["params"], seg_len)
         new_seg, _t, _o, bias = _round_core(w_seg, rng, scenario, part)
-        metrics = {
-            "acc": evaluate(new_seg),
-            "loss": train_loss(new_seg),
-            "bias": bias,
-        }
+        with jax.named_scope(_EVAL_SCOPE):
+            metrics = {
+                "acc": evaluate(new_seg),
+                "loss": train_loss(new_seg),
+                "bias": bias,
+            }
         return {"params": protocols._from_segments(new_seg, spec, mp)}, metrics
 
     # ------------------------------------------------------------------
@@ -925,7 +938,9 @@ def build_sim(
                 state, c * eval_every + jnp.arange(eval_every),
             )
         full = _full_rows(state["w"])
-        metrics = {"acc": evaluate(full), "loss": train_loss(full), **extras}
+        with jax.named_scope(_EVAL_SCOPE):
+            metrics = {"acc": evaluate(full), "loss": train_loss(full),
+                       **extras}
         return state, metrics
 
     def init_scan(scenario: Scenario) -> dict:

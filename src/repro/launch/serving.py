@@ -744,6 +744,7 @@ class ScenarioServer:
                 _ack_cancel(req.future)
                 self.tracker.count("serve/dropped_before_batch")
                 continue
+            self._taken(req)
             batch = [req]
             n = req.cost
             shutdown_after = False
@@ -765,6 +766,7 @@ class ScenarioServer:
                 if n + nxt.cost > self.cfg.max_batch:
                     carry = nxt        # opens the NEXT batch
                     break
+                self._taken(nxt)
                 batch.append(nxt)
                 n += nxt.cost
                 # An urgent/near-deadline arrival shrinks the window for
@@ -776,6 +778,11 @@ class ScenarioServer:
             if shutdown_after:
                 self._put_dispatch(_SHUTDOWN)
                 return
+
+    def _taken(self, req: _Request) -> None:
+        """A request leaves the queue for a batch: its queueing delay."""
+        self.tracker.observe("serve/queue_wait_s",
+                             time.monotonic() - req.t_submit)
 
     def _put_dispatch(self, item) -> None:
         """Blocking put with abort awareness: a hard stop unwedges a
@@ -865,22 +872,21 @@ class ScenarioServer:
                     start += b - a
             else:
                 grid, reqs, slices = d.grid, d.requests, d.slices
-            t0 = time.monotonic()
             try:
                 # Admission already validated per request; grouping +
                 # bucket padding + program-cache lookup happen inside the
                 # warm runner (sharded over the server mesh when one was
                 # given).  Converting the result to numpy is the device
                 # sync (result materialization, not telemetry).
-                res = self.runner.run(
-                    grid, pad_to=self.cfg.batch_buckets, validate=False,
-                )
+                with self.tracker.span("serve/dispatch"):
+                    res = self.runner.run(
+                        grid, pad_to=self.cfg.batch_buckets, validate=False,
+                    )
             except Exception as e:   # keep serving: fail THIS batch only
                 self.tracker.count("serve/dispatch_errors")
                 self._retry_individually(reqs, e)
                 continue
             now = time.monotonic()
-            self.tracker.observe("serve/dispatch_s", now - t0)
             for r, (a, b) in zip(reqs, slices):
                 delivered = _try_resolve(
                     r.future,
@@ -919,16 +925,16 @@ class ScenarioServer:
                 _try_resolve(r.future, exc=ServerStopped("server stopped"))
                 continue
             self.tracker.count("serve/dispatch_retries")
-            t0 = time.monotonic()
             try:
-                res = self.runner.run(
-                    r.grid, pad_to=self.cfg.batch_buckets, validate=False,
-                )
+                with self.tracker.span("serve/dispatch"):
+                    res = self.runner.run(
+                        r.grid, pad_to=self.cfg.batch_buckets,
+                        validate=False,
+                    )
             except Exception as e2:
                 _try_resolve(r.future, exc=e2)
                 continue
             now = time.monotonic()
-            self.tracker.observe("serve/dispatch_s", now - t0)
             if _try_resolve(
                 r.future,
                 result=_slice_result(res, 0, len(r.grid), r.grid.labels),
@@ -1024,6 +1030,7 @@ def main() -> None:
     print(f"served {len(results)} requests in {dt:.2f}s "
           f"({len(results) / dt:.1f} req/s)")
     for k in ("serve/latency_s_p50", "serve/latency_s_p99",
+              "serve/queue_wait_s_p50", "serve/dispatch_s_p50",
               "serve/coalesced_scenarios_mean", "grid/batch_fill_mean",
               "tenant/tenant0/latency_s_p50", "tenant/tenant1/latency_s_p50",
               "cache/hit", "cache/miss", "cache/evict"):

@@ -228,6 +228,26 @@ def test_coalesced_mixed_protocol_serving_bit_identical(toy):
     assert snap["serve/requests"] == 3
 
 
+def test_serving_times_queue_wait_and_dispatch(toy):
+    """Each request's wait in the queue is observed once, when a batch
+    takes it; each dispatch once, through the grid runner's spans."""
+    data, nets, init, apply_fn = toy
+    server = serving.ScenarioServer(
+        init, apply_fn, data, _cfg(),
+        serve=serving.ServeConfig(max_batch=8, max_delay_s=0.25),
+    )
+    with server:
+        server.serve([_grid(nets[0], "ra", "q0"), _grid(nets[1], "ra", "q1")])
+    t = server.tracker
+    waits, latencies = t.samples("serve/queue_wait_s"), t.samples(
+        "serve/latency_s")
+    assert len(waits) == len(latencies) == 2
+    assert max(waits) <= max(latencies)
+    n = int(t.counter("serve/dispatches"))
+    assert len(t.samples("serve/dispatch_s")) == n
+    assert len(t.samples("grid/run_s")) == n
+
+
 def test_partial_batch_bucket_padding_bit_identical(toy):
     """A 3-scenario dispatch padded to a 4-bucket with routing-neutral
     filler returns the unpadded rows bit-identically."""

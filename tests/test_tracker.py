@@ -165,3 +165,40 @@ def test_reset_clears_all_state():
     t.observe("c", 2.0)
     t.reset()
     assert t.snapshot() == {}
+
+
+def _span_sinks():
+    """(tracker to record through, StatsTrackers that should hold the
+    series, the series' name there) per kind of tracker."""
+    s = tr.StatsTracker()
+    a, b = tr.StatsTracker(), tr.StatsTracker()
+    return {
+        "null": (tr.NullTracker(), [], "work_s"),
+        "stats": (s, [s], "work_s"),
+        "scoped": (s.scoped("tenant/a"), [s], "tenant/a/work_s"),
+        "composite": (tr.CompositeTracker([a, tr.NullTracker(), b]), [a, b],
+                      "work_s"),
+    }
+
+
+@pytest.mark.parametrize("kind", ["null", "stats", "scoped", "composite"])
+def test_span_observes_its_seconds_where_the_tracker_records(kind):
+    t, sinks, series = _span_sinks()[kind]
+    with t.span("work", run=1, group="ra+ra_normalized"):
+        pass
+    for sink in sinks:
+        (v,) = sink.samples(series)
+        assert 0.0 <= v < 1.0
+        assert sink.snapshot().keys() == {f"{series}_{k}" for k in
+                                          ("count", "mean", "p50", "p99",
+                                           "max")}
+    if kind == "null":
+        assert t.scoped("x") is t        # nothing to record into
+
+
+def test_span_that_raises_observes_nothing():
+    t = tr.StatsTracker()
+    with pytest.raises(RuntimeError):
+        with t.span("work"):
+            raise RuntimeError("dispatch failed")
+    assert t.samples("work_s") == []
