@@ -46,10 +46,17 @@ def _conv(x, w, stride):
 def conv2d(x, w, stride=1):
     """x: (B, H, W, C); w: (kh, kw, cin, cout); SAME padding.
 
-    The weight gradient is one contraction per kernel tap instead of JAX's
-    transposed convolution: under a `shard_map` (the sharded scenario grid)
-    JAX cannot batch that convolution over a second vmap (scenarios over
-    clients) and raises NotImplementedError.
+    A custom VJP in place of JAX's transposed convolution: under a
+    `shard_map` (the sharded scenario grid) JAX cannot batch that
+    convolution over a second vmap (scenarios over clients) and raises
+    NotImplementedError.  The input gradient is `jax.vjp` of the forward.
+    The weight gradient is one plain convolution of x with the upstream
+    gradient where cin > 1 (`_dw_conv`): it reads both once, where one
+    contraction per kernel tap reads them kh * kw times.  Where cin == 1
+    it stays per tap (`_dw_taps`): there the reads are small, and the
+    convolution form makes the TPU compiler add layout copies of the
+    layer's (B, H, W, scenarios x clients x cout) tensors that cost more
+    than it saves.
     """
     return _conv(x, w, stride)
 
@@ -58,15 +65,17 @@ def _conv2d_fwd(x, w, stride):
     return _conv(x, w, stride), (x, w)
 
 
-def _conv2d_bwd(stride, res, g):
-    x, w = res
-    _, vjp_x = jax.vjp(lambda x_: _conv(x_, w, stride), x)
+def _same_pads(x, w, stride):
+    return jax.lax.padtype_to_pads(x.shape[1:3], w.shape[:2],
+                                   (stride, stride), "SAME")
+
+
+def _dw_taps(x, g, w, stride):
+    """The weight gradient as one (samples x positions) contraction per tap."""
     kh, kw = w.shape[:2]
-    pads = jax.lax.padtype_to_pads(x.shape[1:3], (kh, kw), (stride, stride),
-                                   "SAME")
-    xp = jnp.pad(x, ((0, 0), *pads, (0, 0)))
+    xp = jnp.pad(x, ((0, 0), *_same_pads(x, w, stride), (0, 0)))
     ho, wo = g.shape[1:3]
-    dw = jnp.stack([
+    return jnp.stack([
         jnp.stack([
             jnp.einsum("bhwc,bhwo->co",
                        xp[:, i:i + (ho - 1) * stride + 1:stride,
@@ -75,7 +84,29 @@ def _conv2d_bwd(stride, res, g):
         ])
         for i in range(kh)
     ])
-    return vjp_x(g)[0], dw.astype(w.dtype)
+
+
+def _dw_conv(x, g, w, stride):
+    """The weight gradient as one convolution contracting samples and
+    positions: samples are x's features and g's input features, g is the
+    kernel dilated by the stride, and the output's spatial dims are the
+    taps.  The forward's SAME pads, with the high side set (trimmed where
+    negative) so the window count is exactly (kh, kw); no feature or batch
+    groups, so vmaps fold into groups as they do for the forward."""
+    pads = [(lo, (o - 1) * stride + k - n - lo) for (lo, _), o, k, n in
+            zip(_same_pads(x, w, stride), g.shape[1:3], w.shape[:2],
+                x.shape[1:3])]
+    return jax.lax.conv_general_dilated(
+        x, g, (1, 1), pads, rhs_dilation=(stride, stride),
+        dimension_numbers=("CHWN", "IHWO", "HWNC"),
+    )
+
+
+def _conv2d_bwd(stride, res, g):
+    x, w = res
+    _, vjp_x = jax.vjp(lambda x_: _conv(x_, w, stride), x)
+    dw_fn = _dw_taps if x.shape[-1] == 1 else _dw_conv
+    return vjp_x(g)[0], dw_fn(x, g, w, stride).astype(w.dtype)
 
 
 conv2d.defvjp(_conv2d_fwd, _conv2d_bwd)

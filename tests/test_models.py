@@ -265,13 +265,22 @@ def test_sim_registry_all_models():
         registry.nwp_cfg("whisper_base")
 
 
-@pytest.mark.parametrize("hw,cin,cout,k,stride", [
-    (7, 2, 3, 3, 1),    # the CNN's 3x3 SAME convs
-    (8, 3, 4, 3, 2),    # ResNet's strided stage entry
-    (9, 2, 5, 1, 2),    # ResNet's 1x1 strided projection
-])
-def test_conv2d_vjp_matches_native(hw, cin, cout, k, stride):
-    """The per-tap weight gradient equals JAX's transposed convolution."""
+_CONV_CASES = [  # hw, cin, cout, k, stride, atol
+    (7, 2, 3, 3, 1, 1e-5),      # the CNN's 3x3 SAME convs
+    (8, 3, 4, 3, 2, 1e-5),      # ResNet's strided stage entry
+    (9, 2, 5, 1, 2, 1e-5),      # ResNet's 1x1 strided projection
+    # The CNN's two layers at full width: gradients of up to ~60 summed
+    # over 2 * 28 * 28 terms, so float32 summation order shows at 3e-5.
+    (28, 1, 32, 3, 1, 1e-4),    # conv1: per-tap weight gradient
+    (14, 32, 64, 3, 1, 1e-4),   # conv2: one-convolution weight gradient
+]
+
+
+@pytest.mark.parametrize("hw,cin,cout,k,stride,atol", _CONV_CASES,
+                         ids=["-".join(map(str, c[:5])) for c in _CONV_CASES])
+def test_conv2d_vjp_matches_native(hw, cin, cout, k, stride, atol):
+    """The custom weight gradient (per tap where cin == 1, one convolution
+    otherwise) equals JAX's transposed convolution."""
     from repro.models import smallnets
 
     kx, kw = jax.random.split(jax.random.PRNGKey(0))
@@ -284,10 +293,11 @@ def test_conv2d_vjp_matches_native(hw, cin, cout, k, stride):
     got = jax.grad(loss(smallnets.conv2d), (0, 1))(x, w)
     want = jax.grad(loss(smallnets._conv), (0, 1))(x, w)
     for g, r in zip(got, want):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=1e-5)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=atol)
 
 
-def test_conv2d_grad_batches_under_shard_map():
+@pytest.mark.parametrize("cin", [1, 3])   # per-tap and convolution paths
+def test_conv2d_grad_batches_under_shard_map(cin):
     """Scenarios x clients vmap of a conv weight gradient inside shard_map
     (the sharded scenario grid): JAX's own transposed convolution raises
     NotImplementedError there."""
@@ -296,8 +306,8 @@ def test_conv2d_grad_batches_under_shard_map():
     from repro.models import smallnets
 
     mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("grid",))
-    x = jax.random.normal(jax.random.PRNGKey(1), (3, 2, 5, 5, 1))
-    w = jax.random.normal(jax.random.PRNGKey(2), (2, 3, 3, 3, 1, 2))
+    x = jax.random.normal(jax.random.PRNGKey(1), (3, 2, 5, 5, cin))
+    w = jax.random.normal(jax.random.PRNGKey(2), (2, 3, 3, 3, cin, 2))
 
     def grid(conv):
         def loss(w_, x_):
@@ -311,3 +321,40 @@ def test_conv2d_grad_batches_under_shard_map():
     got = jax.jit(sharded)(w)
     want = jax.jit(grid(smallnets._conv))(w)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5)
+
+
+def test_cnn_weight_gradient_path_per_layer():
+    """Which CNN layers take the one-convolution weight gradient, as the
+    scenarios x clients program lowers it: conv2 (cin 32) one convolution
+    and no per-tap contractions, conv1 (cin 1) its nine taps, and every
+    convolution at float32 "highest"."""
+    import re
+
+    from repro.fl.simulator import _float32_matmuls
+    from repro.models import smallnets
+
+    n_scen, n_clients, batch = 2, 3, 4
+    params = jax.tree.map(
+        lambda l: jnp.broadcast_to(l, (n_scen, n_clients) + l.shape),
+        smallnets.init_cnn(jax.random.PRNGKey(0)))
+    x = jnp.zeros((n_scen, n_clients, batch, 28, 28, 1))
+
+    def loss(p, x_):
+        return jnp.sum(smallnets.apply_cnn(p, x_))
+
+    grads = jax.vmap(jax.vmap(jax.grad(loss)))
+    text = jax.jit(_float32_matmuls(grads)).lower(params, x).as_text()
+    convs = [l for l in text.splitlines() if "stablehlo.convolution" in l]
+    dots = [l for l in text.splitlines() if "stablehlo.dot_general" in l]
+    # Samples as features of x and g, taps as the output's spatial dims.
+    dw_convs = [l for l in convs if "[f, 0, 1, b]x[i, 0, 1, o]->[0, 1, b, f]" in l]
+    assert len(dw_convs) == 1
+    assert re.search(r"-> tensor<3x3x\d+x\d+xf32>", dw_convs[0])
+    # conv2's taps would be (scenario, client, 32, 64) contractions.
+    assert not [l for l in dots
+                if f"-> tensor<{n_scen}x{n_clients}x32x64xf32>" in l]
+    assert len([l for l in dots
+                if f"-> tensor<{n_scen}x{n_clients}x1x32xf32>" in l]) == 9
+    assert convs and all(
+        "precision_config = [#stablehlo<precision HIGHEST>, "
+        "#stablehlo<precision HIGHEST>]" in l for l in convs)
