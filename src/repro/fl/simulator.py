@@ -42,11 +42,12 @@ reproduces the open-loop participation path bitwise.
 Segment-native state + model-axis sharding (DESIGN.md §13): the round
 scan carries the paper's exchange representation — client-stacked segment
 rows ``(N, S, seg_len)`` — natively; the pytree <-> segment codec runs
-once per `run_scenario`, at the boundary, and local training
-differentiates through the row layout.  ``build_sim(model_shards=Dm)``
-additionally shards the segment axis over a ``model`` mesh axis inside
-each scenario (`run_scenario` then runs under `shard_map`; see
-`repro.fl.scenarios` / `launch.mesh.grid_model_mesh`), and the
+once per `run_scenario`, at the boundary, and each local epoch takes
+each client's pytree out of its row and lays the update back.
+``build_sim(model_shards=Dm)`` additionally shards the segment axis over a
+``model`` mesh axis inside each scenario (`run_scenario` then runs under
+`shard_map`; see `repro.fl.scenarios` / `launch.mesh.grid_model_mesh`),
+and the
 ``init_scan`` / ``advance_chunk`` pair exposes the scan state for the
 preemption-safe checkpoint runner (`repro.checkpoint.checkpoint`).
 
@@ -495,11 +496,10 @@ def build_sim(
     rows ``(N, S, seg_len)`` — and the pytree <-> segment codec
     (`protocols._to_segments` / `_from_segments`) runs exactly once per
     `run_scenario`, at the boundary, never inside the round scan.  Local
-    training differentiates the loss *through the row layout*
-    (``jax.grad(loss ∘ leaf_views)``): reshape/split/slice are exact
-    layout moves with exact-scatter transposes, so per-leaf gradients —
-    and the trained trajectory — are bitwise what the pytree carry
-    produced.
+    training takes each client's pytree as exact layout views of its row
+    (reshape/split/slice) every epoch, differentiates the loss on those
+    leaves and lays the updated leaves back by one concatenation, so the
+    trained trajectory is bitwise what the pytree carry produced.
 
     Args:
       init_fn: model init, `key -> params` pytree (one shared init; the
@@ -601,9 +601,8 @@ def build_sim(
         """One client's parameter pytree as pure layout views of its row.
 
         ``row`` is a full (S, K) — or flattened-compatible — segment row;
-        entries past ``m_params`` are codec padding (zero, and kept zero by
-        training: the flatten-slice's transpose scatters gradient only
-        into the first ``m_params`` positions).
+        entries past ``m_params`` are codec padding (zero; local training
+        carries them over unchanged, see `_row_of`).
         """
         flat = row.reshape(-1)[:m_params]
         parts = jnp.split(flat, leaf_splits)
@@ -648,30 +647,50 @@ def build_sim(
     def _row_loss(row, x, y):
         return loss(_leaf_views(row), x, y)
 
+    def _row_of(params: Pytree, row: jnp.ndarray) -> jnp.ndarray:
+        """Lay one client's ``params`` back into ``row``'s layout; the codec
+        padding past ``m_params`` is carried over from ``row``."""
+        flat = row.reshape(-1)
+        parts = [leaf.reshape(-1) for leaf in jax.tree_util.tree_leaves(params)]
+        return jnp.concatenate(parts + [flat[m_params:]]).reshape(row.shape)
+
     def local_train(rows, lr, epochs=None):
         """Local training for `local_epochs` epochs (paper eq. 3), per client.
 
-        ``rows`` are FULL segment rows (N, S[_pad], K); the gradient flows
-        through the leaf views, so the update is the per-leaf step laid
-        out in row coordinates (codec padding receives zero gradient).
+        ``rows`` are FULL segment rows (N, S[_pad], K), and the epoch scan
+        carries them.  Each epoch takes the client's pytree out of its row
+        (`_leaf_views`, behind a fusion barrier), differentiates the loss on
+        those leaves and lays the updated leaves back (`_row_of`).  On a
+        TPU v5e the two nearby forms departed from a plain reference: the
+        gradient taken through the row views (ResNet-18-GN, a loss after
+        one step about twice the reference's) and the leaves carried
+        through the epochs (the two-epoch CNN, loss gaps 5e-3 to 4e-2
+        against 5e-4).  The barrier gives the leaves buffers of their own:
+        without it the CharRNN program's temporaries grow by 0.9 GB.
         ``epochs`` (optional, (N,) int32) enables heterogeneous compute: the
         scan still runs the static `local_epochs` bound, but client m's
         update is masked out after its own epoch count (values clip to the
         bound).  ``epochs=None`` keeps the exact static trace.
 
         With a bound ``local_optimizer`` the scan carries (row, opt_state)
-        per client — state freshly `init`-ed each call (= each round) —
-        and the heterogeneous-epochs mask freezes BOTH row and state past
-        a client's own epoch count.  ``local_optimizer=None`` is plain GD,
-        the exact historical trace.
+        per client — state freshly `init`-ed each call (= each round) on
+        the leaves — and the heterogeneous-epochs mask freezes BOTH row and
+        state past a client's own epoch count.  ``local_optimizer=None`` is
+        plain GD.
         """
         opt = None if opt_factory is None else opt_factory(lr)
 
         def step(r, st, x, y):
-            g = jax.grad(_row_loss)(r, x, y)
+            prm = _fusion_barrier(_leaf_views(r))
+            g = jax.grad(loss)(prm, x, y)
             if opt is None:
-                return r - lr * g, st
-            return opt.update(r, g, st)
+                new = jax.tree.map(lambda a, b: a - lr * b, prm, g)
+            else:
+                new, st = opt.update(prm, g, st)
+            return _row_of(new, r), st
+
+        def init_state(row):
+            return None if opt is None else opt.init(_leaf_views(row))
 
         if epochs is None:
             def train_one(row, x, y):
@@ -679,8 +698,7 @@ def build_sim(
                     r, st = carry
                     return step(r, st, x, y), None
 
-                st0 = None if opt is None else opt.init(row)
-                (row, _), _ = jax.lax.scan(body, (row, st0), None,
+                (row, _), _ = jax.lax.scan(body, (row, init_state(row)), None,
                                            length=local_epochs)
                 return row
 
@@ -700,8 +718,7 @@ def build_sim(
                     )
                 return (r2, st2), None
 
-            st0 = None if opt is None else opt.init(row)
-            (row, _), _ = jax.lax.scan(body, (row, st0),
+            (row, _), _ = jax.lax.scan(body, (row, init_state(row)),
                                        jnp.arange(local_epochs))
             return row
 

@@ -2,7 +2,9 @@
 
   * CNN      — 2 conv layers (32/64 filters) + pool + 2 FC, ReLU
                (Fed-fashionMNIST task).
-  * ResNet   — CIFAR-style ResNet-n (n=18, 56) with shortcut connections.
+  * ResNet   — CIFAR-style ResNet-n (n=18, 56) with shortcut connections,
+               or any stages and widths, optionally with GroupNorm
+               (ResNet-18-GN at He et al.'s widths).
   * CharRNN  — embedding + 2-layer LSTM (256 hidden) + FC output
                (Shakespeare next-character prediction, vocab 90).
   * MLP      — small classifier for fast CPU-scale FL experiments.
@@ -145,30 +147,92 @@ def apply_cnn(params, x):
 
 
 # ---------------------------------------------------------------------------
-# ResNet (CIFAR-style: 3 stages, 2n blocks per stage for ResNet-6n+2)
+# Group normalisation (Wu & He, arXiv 1803.08494)
 # ---------------------------------------------------------------------------
-def init_resnet(key, *, depth=18, in_ch=3, n_classes=10, width=16) -> Pytree:
-    """depth in {18 -> (2,2,2) basic-ish stages at width; 56 -> (9,9,9)}."""
+GN_EPS = 1e-5
+
+
+def init_group_norm(groups: int, channels: int) -> Pytree:
+    """Scale 1 and bias 0, each shaped (groups, channels // groups): the
+    group count is the leading dim, so `group_norm` reads it from the
+    parameters.  Row-major, the values lie in channel order."""
+    if channels % groups:
+        raise ValueError(f"{channels} channels do not split into "
+                         f"{groups} groups")
+    shape = (groups, channels // groups)
+    return {"scale": jnp.ones(shape, jnp.float32),
+            "bias": jnp.zeros(shape, jnp.float32)}
+
+
+def group_norm(x, p, eps=GN_EPS):
+    """x: (B, H, W, C).  Each sample's channels fall into G contiguous
+    groups; every group is normalised by the mean and (biased) variance
+    over its H * W * C/G values, then scaled and shifted per channel."""
+    with jax.named_scope("group_norm"):
+        b, h, w, c = x.shape
+        g, cg = p["scale"].shape
+        xg = x.reshape(b, h, w, g, cg)
+        mean = jnp.mean(xg, axis=(1, 2, 4), keepdims=True)
+        var = jnp.mean(jnp.square(xg - mean), axis=(1, 2, 4), keepdims=True)
+        y = (xg - mean) * jax.lax.rsqrt(var + eps)
+        return (y * p["scale"] + p["bias"]).reshape(b, h, w, c)
+
+
+# ---------------------------------------------------------------------------
+# ResNet (CIFAR-style: a 3x3 stem at stride 1, no max-pool, stages of basic
+# blocks, the first stage at stride 1 and each later one entered at stride 2)
+# ---------------------------------------------------------------------------
+def _depth_blocks(depth: int) -> tuple[int, ...]:
     if depth == 18:
-        blocks = (2, 2, 2)
-    elif depth == 56:
-        blocks = (9, 9, 9)
-    else:
-        n = (depth - 2) // 6
-        blocks = (n, n, n)
+        return (2, 2, 2)
+    if depth == 56:
+        return (9, 9, 9)
+    n = (depth - 2) // 6
+    return (n, n, n)
+
+
+def init_resnet(key, *, depth=18, in_ch=3, n_classes=10, width=16,
+                stage_blocks=None, stage_widths=None, gn_groups=0) -> Pytree:
+    """Basic-block ResNet.
+
+    By default three stages at ``width`` x (1, 2, 4) with the blocks per
+    stage from ``depth`` (18 -> (2, 2, 2), 56 -> (9, 9, 9), else
+    (depth - 2) // 6 each), and no normalisation.  ``stage_blocks`` and
+    ``stage_widths`` (one entry per stage) replace that; the stem has the
+    first stage's width.  ``gn_groups`` > 0 puts a `group_norm` of that
+    many groups after every convolution, the projection shortcut's too
+    (He et al.'s ResNet-18, arXiv 1512.03385, with GroupNorm: stage_blocks
+    (2, 2, 2, 2), stage_widths (64, 128, 256, 512), gn_groups 2).  A
+    stage whose width differs from its input's is entered through a 1x1
+    projection.  Keys come from one split in layer order (stem, each
+    block's conv1, conv2 and projection, fc); norms draw none.
+    """
+    blocks = (_depth_blocks(depth) if stage_blocks is None
+              else tuple(stage_blocks))
+    widths = (tuple(width * 2**s for s in range(len(blocks)))
+              if stage_widths is None else tuple(stage_widths))
+    if len(widths) != len(blocks):
+        raise ValueError(f"{len(blocks)} stages but {len(widths)} widths")
+
+    norm = partial(init_group_norm, gn_groups)
     ks = iter(jax.random.split(key, 4 + 2 * sum(blocks) + len(blocks)))
-    p: dict = {"stem": _conv_init(next(ks), 3, 3, in_ch, width)}
-    cin = width
-    for s, nb in enumerate(blocks):
-        cout = width * (2**s)
+    p: dict = {"stem": _conv_init(next(ks), 3, 3, in_ch, widths[0])}
+    if gn_groups:
+        p["stem_norm"] = norm(widths[0])
+    cin = widths[0]
+    for s, (nb, cout) in enumerate(zip(blocks, widths)):
         stage = []
-        for b in range(nb):
+        for _ in range(nb):
             blk = {
                 "conv1": _conv_init(next(ks), 3, 3, cin, cout),
                 "conv2": _conv_init(next(ks), 3, 3, cout, cout),
             }
             if cin != cout:
                 blk["proj"] = _conv_init(next(ks), 1, 1, cin, cout)
+            if gn_groups:
+                blk["norm1"], blk["norm2"] = norm(cout), norm(cout)
+                if "proj" in blk:
+                    blk["proj_norm"] = norm(cout)
             stage.append(blk)
             cin = cout
         p[f"stage{s}"] = stage
@@ -176,24 +240,49 @@ def init_resnet(key, *, depth=18, in_ch=3, n_classes=10, width=16) -> Pytree:
     return p
 
 
+def _norm(p, name, x):
+    """`group_norm` by ``p[name]`` where the model has that norm."""
+    return group_norm(x, p[name]) if name in p else x
+
+
+def _basic_block(blk, x, stride):
+    h = jax.nn.relu(_norm(blk, "norm1", conv2d(x, blk["conv1"], stride=stride)))
+    h = _norm(blk, "norm2", conv2d(h, blk["conv2"]))
+    sc = x
+    if "proj" in blk:
+        sc = _norm(blk, "proj_norm", conv2d(x, blk["proj"], stride=stride))
+    elif stride != 1:
+        sc = x[:, ::stride, ::stride]
+    return jax.nn.relu(h + sc)
+
+
+# From a stem of `REMAT_WIDTH` channels up (ResNet-18's 64), each block's
+# activations are recomputed in the backward pass from its input: full-batch
+# training of every client model of a dispatch would otherwise keep all 18
+# layers' activations at once (about 7 MB per 32x32 image and model at
+# ResNet-18's widths).  Narrower nets, such as the default width 16, keep
+# them and pay no extra forward pass.
+REMAT_WIDTH = 64
+_remat_block = jax.checkpoint(_basic_block, static_argnums=(2,))
+
+
 def apply_resnet(params, x):
-    x = jax.nn.relu(conv2d(x, params["stem"]))
-    s = 0
-    while f"stage{s}" in params:
-        stride = 1 if s == 0 else 2
-        for i, blk in enumerate(params[f"stage{s}"]):
-            st = stride if i == 0 else 1
-            h = jax.nn.relu(conv2d(x, blk["conv1"], stride=st))
-            h = conv2d(h, blk["conv2"])
-            sc = x
-            if "proj" in blk:
-                sc = conv2d(x, blk["proj"], stride=st)
-            elif st != 1:
-                sc = x[:, ::st, ::st]
-            x = jax.nn.relu(h + sc)
-        s += 1
-    x = jnp.mean(x, axis=(1, 2))  # global average pool
-    return x @ params["fc"]["w"] + params["fc"]["b"]
+    """x: (B, H, W, C) -> logits; the stages, their strides and the norms
+    are read from the parameter tree."""
+    block = (_remat_block if params["stem"].shape[-1] >= REMAT_WIDTH
+             else _basic_block)
+    with jax.named_scope("resnet"):
+        with jax.named_scope("stem"):
+            x = jax.nn.relu(_norm(params, "stem_norm",
+                                  conv2d(x, params["stem"])))
+        s = 0
+        while f"stage{s}" in params:
+            with jax.named_scope(f"stage{s}"):
+                for i, blk in enumerate(params[f"stage{s}"]):
+                    x = block(blk, x, 2 if s > 0 and i == 0 else 1)
+            s += 1
+        x = jnp.mean(x, axis=(1, 2))  # global average pool
+        return x @ params["fc"]["w"] + params["fc"]["b"]
 
 
 # ---------------------------------------------------------------------------

@@ -358,3 +358,108 @@ def test_cnn_weight_gradient_path_per_layer():
     assert convs and all(
         "precision_config = [#stablehlo<precision HIGHEST>, "
         "#stablehlo<precision HIGHEST>]" in l for l in convs)
+
+
+def test_resnet_default_tree_is_kept():
+    """Without the stage, width and GroupNorm keywords `init_resnet`
+    builds the tree it always has: three stages at width x (1, 2, 4), a
+    projection where the width changes, no norms, and keys from one split
+    of 4 + 2 * blocks + stages in layer order."""
+    from repro.models import smallnets
+
+    key = jax.random.PRNGKey(0)
+    p = smallnets.init_resnet(key, depth=18, width=8)
+    assert sorted(p) == ["fc", "stage0", "stage1", "stage2", "stem"]
+    assert [sorted(b) for s in ("stage0", "stage1", "stage2")
+            for b in p[s]] == [["conv1", "conv2"]] * 2 + [
+                ["conv1", "conv2", "proj"], ["conv1", "conv2"]] * 2
+    assert p["stage2"][0]["proj"].shape == (1, 1, 16, 32)
+    assert sum(l.size for l in jax.tree.leaves(p)) == 43_810
+    ks = jax.random.split(key, 4 + 2 * 6 + 3)
+    np.testing.assert_array_equal(p["stem"], smallnets._conv_init(ks[0], 3, 3, 3, 8))
+    np.testing.assert_array_equal(p["stage1"][0]["proj"],
+                                  smallnets._conv_init(ks[7], 1, 1, 8, 16))
+    np.testing.assert_array_equal(p["fc"]["w"],
+                                  smallnets._fc_init(ks[15], 32, 10)["w"])
+
+
+def test_resnet_scopes_name_stem_stages_and_group_norm():
+    """The stem, each stage and each GroupNorm are named scopes in the
+    compiled training step, so its time splits by stage offline."""
+    import re
+
+    from repro.models import smallnets
+
+    p = smallnets.init_resnet(jax.random.PRNGKey(0), stage_blocks=(1, 1, 1, 1),
+                              stage_widths=(4, 8, 8, 16), gn_groups=2)
+    x = jnp.zeros((2, 8, 8, 3))
+    step = jax.jit(jax.grad(lambda p_: jnp.sum(smallnets.apply_resnet(p_, x))))
+    names = re.findall(r'op_name="([^"]*)"',
+                       step.lower(p).compile().as_text())
+    # A transformation wraps the outer scope: jvp(resnet)/stage0/...
+    for part in ["stem", "stage0", "stage1", "stage2", "stage3"]:
+        assert any(re.search(rf"(\w+\()*resnet\)*/{part}/", n)
+                   for n in names), part
+    assert any(re.search(r"resnet\)*/stage\d/group_norm/", n) for n in names)
+
+
+def test_resnet_weight_gradient_path_per_layer():
+    """Every ResNet-18-GN convolution takes the one-convolution weight
+    gradient as the scenarios x clients program lowers it: the stem (cin 3;
+    on a v5e 1.9 ms against 9.0 ms per tap for 20 models x 65 images at
+    32x32), the sixteen 3x3 block convolutions (stride 2 at each later
+    stage's entry, as rhs dilation 2) and the three 1x1 projections; no
+    per-tap contractions, and every convolution at float32 "highest"."""
+    import re
+
+    from repro.fl.simulator import _float32_matmuls
+    from repro.models import smallnets
+
+    n_scen, n_clients, batch = 2, 3, 4
+    params = jax.tree.map(
+        lambda l: jnp.broadcast_to(l, (n_scen, n_clients) + l.shape),
+        smallnets.init_resnet(jax.random.PRNGKey(0), stage_blocks=(2, 2, 2, 2),
+                              stage_widths=(8, 16, 32, 64), gn_groups=2))
+    x = jnp.zeros((n_scen, n_clients, batch, 8, 8, 3))
+
+    def loss(p, x_):
+        return jnp.sum(smallnets.apply_resnet(p, x_))
+
+    grads = jax.vmap(jax.vmap(jax.grad(loss)))
+    text = jax.jit(_float32_matmuls(grads)).lower(params, x).as_text()
+    convs = [l for l in text.splitlines() if "stablehlo.convolution" in l]
+    dots = [l for l in text.splitlines() if "stablehlo.dot_general" in l]
+    dw = [l for l in convs if "[f, 0, 1, b]x[i, 0, 1, o]->[0, 1, b, f]" in l]
+    m = n_scen * n_clients
+    kernels = sorted((re.search(r"-> tensor<(\d+)x\d+x(\d+)x(\d+)xf32>", l).groups(),
+                      re.search(r"rhs_dilate = \[(\d), \d\]", l).group(1))
+                     for l in dw)
+    want = ([(("1", str(c), str(2 * c * m)), "2") for c in (8, 16, 32)]
+            + [(("3", "3", str(8 * m)), "1")]
+            + [(("3", str(c), str(c * m)), "1") for c in (8, 16, 32, 64)
+               for _ in range(4 if c == 8 else 3)]
+            + [(("3", str(c), str(2 * c * m)), "2") for c in (8, 16, 32)])
+    assert kernels == sorted(want)
+    # No per-tap contractions: the only products are the output layer's
+    # two gradients.
+    assert len(dots) == 2
+    assert convs and all(
+        "precision_config = [#stablehlo<precision HIGHEST>, "
+        "#stablehlo<precision HIGHEST>]" in l for l in convs)
+
+
+@pytest.mark.parametrize("stem_width,remat", [(16, False), (64, True)])
+def test_resnet_rematerialises_blocks_from_the_published_width(stem_width, remat):
+    """Blocks are recomputed in the backward pass only from a stem of
+    ResNet-18's 64 channels up; the default width-16 net keeps its
+    activations and traces no rematerialisation."""
+    from repro.models import smallnets
+
+    widths = tuple(stem_width * 2**s for s in range(3))
+    p = smallnets.init_resnet(jax.random.PRNGKey(0), stage_blocks=(1, 1, 1),
+                              stage_widths=widths)
+    x = jnp.zeros((2, 8, 8, 3))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p_: jnp.sum(smallnets.apply_resnet(p_, x))))(p)
+    names = {e.primitive.name for e in jaxpr.jaxpr.eqns}
+    assert ("remat2" in names) == remat
