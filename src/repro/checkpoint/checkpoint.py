@@ -34,6 +34,8 @@ import numpy as np
 
 from jax import shard_map
 
+from repro.fl import simulator
+
 Pytree = Any
 
 
@@ -285,7 +287,11 @@ def run_resumable(
     `sim.run_scenario` scans over, so a run interrupted at any chunk and
     resumed from its checkpoint replays a bitwise-identical program.
     Each checkpoint records the scan state (which carries the PRNG key),
-    the metric rows accumulated so far, and the round index.
+    the metric rows accumulated so far, and the round index.  The rows
+    are saved as `advance_chunk` emits them (``loss_in`` / ``loss_end``,
+    not ``loss``): chunk c's ``loss`` is taken from chunk c+1's first
+    local epoch and the last chunk computes its own, so
+    `simulator.chunk_metrics` builds ``loss`` once every row is in.
 
     Args:
       sim: a `repro.fl.simulator.SimPrograms`.
@@ -371,10 +377,4 @@ def run_resumable(
     metrics = _stack_rows(prev_rows, rows)
     if metrics is None:
         raise ValueError("run_resumable: sim has zero chunks to run")
-    if sim.eval_every > 1:
-        metrics["bias"] = np.asarray(metrics["bias"]).reshape(-1)
-        if "selected" in metrics:
-            metrics["selected"] = np.asarray(
-                metrics["selected"]
-            ).reshape(-1, sim.n_clients)
-    return metrics
+    return simulator.chunk_metrics(metrics)

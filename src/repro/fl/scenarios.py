@@ -1089,10 +1089,12 @@ class GridRunner:
         int (first k devices), or None for the single-device vmap path.
         Overridable per call.
       tracker: metrics sink (`repro.launch.tracker.Tracker`) for cache
-        hit/miss/evict counters, batch fill ratios and the ``grid/*``
-        spans of each `run` (``run``, ``validate``, per dispatch group
-        ``prepare`` and ``dispatch``, ``collect``); defaults to the
-        no-op NullTracker.
+        hit/miss/evict counters, batch fill ratios, the
+        ``grid/train_loss_reused`` counter (chunk-end train losses taken
+        from the next chunk's first local epoch instead of a pass of their
+        own, per real scenario) and the ``grid/*`` spans of each `run`
+        (``run``, ``validate``, per dispatch group ``prepare`` and
+        ``dispatch``, ``collect``); defaults to the no-op NullTracker.
       max_cached_programs: LRU bound on the compiled-program cache
         (DESIGN.md §11).  None = unbounded — fine for one-shot figure
         runs, a leak for a long-lived server (the serving engine always
@@ -1244,6 +1246,10 @@ class GridRunner:
                         program, args = self._program_sharded(sub, mesh)
                 with self.tracker.span("grid/dispatch", **meta):
                     metrics = program(args)
+                    # Each real scenario took n_chunks - 1 of its chunk-end
+                    # train losses from the next chunk's first local epoch.
+                    self.tracker.count("grid/train_loss_reused", len(idx) * (
+                        self.sim.n_chunks - self.sim.train_loss_passes))
                     # Unpad: filler rows (j >= len(idx)) are never read.
                     # The row slices queue on the device behind this
                     # program, while the next group's program runs.

@@ -69,6 +69,7 @@ Public API
   build_sim(...)            bind (init, apply, data, statics) -> SimPrograms
   SimPrograms.round_step    (state, rng, scenario) -> (state, metrics)
   SimPrograms.run_scenario  scenario -> metrics dict (scanned n_rounds)
+  chunk_metrics             stacked advance_chunk rows -> metrics dict
   run / simulate            scalar one-scenario entry point -> SimResult
   metrics_to_result         metrics dict -> SimResult
   ROUND_SCOPES              the round's phases as `jax.named_scope`s
@@ -82,7 +83,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import warnings
-from typing import Any, Callable, NamedTuple
+from typing import Any, Callable, ClassVar, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -445,11 +446,15 @@ class SimPrograms:
     the segment-native scan state ``{"w": (N, L_local, K) rows, "key": key
     [, "sig": SelectionSignals]}`` and ``advance_chunk(state, scenario, c)``
     advances chunk ``c`` (= ``eval_every`` rounds, one metrics row).
-    `run_scenario` itself is a `lax.scan` of `advance_chunk`, so a host
-    loop that jits `advance_chunk` once and feeds chunks ``0..n_chunks-1``
-    (see `repro.checkpoint.checkpoint.run_resumable`) replays the same
-    per-chunk program whether or not it was interrupted in between —
+    `run_scenario` itself is a `lax.scan` of `advance_chunk` followed by
+    `chunk_metrics`, so a host loop that jits `advance_chunk` once, feeds
+    chunks ``0..n_chunks-1`` and applies `chunk_metrics` to the stacked
+    rows (see `repro.checkpoint.checkpoint.run_resumable`) replays the
+    same per-chunk program whether or not it was interrupted in between —
     that, not floating-point luck, is the bitwise-resume guarantee.
+    Chunk c's train ``loss`` comes from chunk c+1's first local epoch;
+    only the last chunk runs a train-loss pass of its own
+    (``train_loss_passes``).
 
     With ``model_shards > 1`` the ``"w"`` rows are the LOCAL model-axis
     shard and `run_scenario` / `init_scan` / `advance_chunk` must run
@@ -471,6 +476,9 @@ class SimPrograms:
     local_segments: int   # L_local = ceil(S / model_shards)
     seg_len: int
     bits_per_value: int = errors.FLOAT_BITS  # from the bound state dtype
+    # Standalone train-loss forwards per scenario: only the last chunk's;
+    # every other chunk's loss is the next chunk's first local epoch.
+    train_loss_passes: ClassVar[int] = 1
 
 
 def build_sim(
@@ -677,30 +685,41 @@ def build_sim(
         the leaves — and the heterogeneous-epochs mask freezes BOTH row and
         state past a client's own epoch count.  ``local_optimizer=None`` is
         plain GD.
+
+        Returns ``(rows, loss0)``: ``loss0`` (N,) is each client's
+        full-batch train loss on the rows it ENTERED with — the value of
+        the first epoch's forward (`jax.value_and_grad`), computed for
+        every client, masked-out and zero-epoch ones included.  It is the
+        previous chunk's end-of-chunk train loss (`advance_chunk`).
         """
         opt = None if opt_factory is None else opt_factory(lr)
 
         def step(r, st, x, y):
             prm = _fusion_barrier(_leaf_views(r))
-            g = jax.grad(loss)(prm, x, y)
+            val, g = jax.value_and_grad(loss)(prm, x, y)
             if opt is None:
                 new = jax.tree.map(lambda a, b: a - lr * b, prm, g)
             else:
                 new, st = opt.update(prm, g, st)
-            return _row_of(new, r), st
+            return (_row_of(new, r), st), val
 
         def init_state(row):
             return None if opt is None else opt.init(_leaf_views(row))
+
+        def first_loss(vals, row, x, y):
+            # A zero-epoch scan runs no forward: take the loss on its own.
+            return vals[0] if local_epochs else _row_loss(row, x, y)
 
         if epochs is None:
             def train_one(row, x, y):
                 def body(carry, _):
                     r, st = carry
-                    return step(r, st, x, y), None
+                    return step(r, st, x, y)
 
-                (row, _), _ = jax.lax.scan(body, (row, init_state(row)), None,
-                                           length=local_epochs)
-                return row
+                (out, _), vals = jax.lax.scan(
+                    body, (row, init_state(row)), None, length=local_epochs
+                )
+                return out, first_loss(vals, row, x, y)
 
             return jax.vmap(train_one)(rows, xs, ys)
 
@@ -709,18 +728,18 @@ def build_sim(
         def train_one_masked(row, x, y, ep):
             def body(carry, i):
                 r, st = carry
-                r2, st2 = step(r, st, x, y)
+                (r2, st2), val = step(r, st, x, y)
                 keep = i < ep
                 r2 = jnp.where(keep, r2, r)
                 if st is not None:
                     st2 = jax.tree.map(
                         lambda a, b: jnp.where(keep, a, b), st2, st
                     )
-                return (r2, st2), None
+                return (r2, st2), val
 
-            (row, _), _ = jax.lax.scan(body, (row, init_state(row)),
-                                       jnp.arange(local_epochs))
-            return row
+            (out, _), vals = jax.lax.scan(body, (row, init_state(row)),
+                                          jnp.arange(local_epochs))
+            return out, first_loss(vals, row, x, y)
 
         return jax.vmap(train_one_masked)(rows, xs, ys, epochs)
 
@@ -749,10 +768,12 @@ def build_sim(
         (N, S, K) rows when ``model_shards == 1``).  ``part`` is the
         realized (N,) participation mask (None = full, the exact
         pre-dynamic trace).  Returns ``(new_loc, trained_full, old_full,
-        bias)`` — the full-row trained / previous states feed the closed
-        loop's signal refresh.  Both `_advance` and `_advance_closed` run
-        THIS code, so the open- and closed-loop paths cannot drift apart —
-        the uniform policy's bit-identity with the open loop rests on it.
+        bias, loss_in)`` — the full-row trained / previous states feed the
+        closed loop's signal refresh; ``loss_in`` (N,) is every client's
+        train loss on ``old_full``, from `local_train`'s first epoch.
+        Both `_advance` and `_advance_closed` run THIS code, so the open-
+        and closed-loop paths cannot drift apart — the uniform policy's
+        bit-identity with the open loop rests on it.
 
         The codec (DESIGN.md §15) slots between training and delivery: it
         encodes the REPLICATED full rows (transmit mask + quantization
@@ -767,7 +788,8 @@ def build_sim(
         """
         w_full = _full_rows(w_loc)
         with jax.named_scope(_TRAIN_SCOPE):
-            trained = local_train(w_full, scenario.lr, scenario.local_epochs)
+            trained, loss_in = local_train(w_full, scenario.lr,
+                                           scenario.local_epochs)
             if part is not None:
                 trained = jnp.where(part[:, None, None] > 0, trained, w_full)
         with jax.named_scope(_EXCHANGE_SCOPE):
@@ -799,16 +821,17 @@ def build_sim(
                 # INPUT (the encoded w_ex); a client that sat the round out
                 # must keep its unencoded state instead.
                 new_loc = jnp.where(part[:, None, None] > 0, new_loc, w_raw)
-        return new_loc, trained, w_full, bias
+        return new_loc, trained, w_full, bias, loss_in
 
     def _advance(w_loc: jnp.ndarray, rng: jax.Array, scenario: Scenario):
-        """Train + exchange, NO metric evaluation: (w_loc, bias)."""
+        """Train + exchange, NO metric evaluation: (w_loc, bias, loss_in)."""
         part = scenario.participation
         if part is not None:
             part = part[:n]
-        new_loc, _trained, _old, bias = _round_core(w_loc, rng, scenario,
-                                                    part)
-        return new_loc, bias
+        new_loc, _trained, _old, bias, loss_in = _round_core(
+            w_loc, rng, scenario, part
+        )
+        return new_loc, bias, loss_in
 
     def _advance_closed(w_loc: jnp.ndarray, rng: jax.Array,
                         scenario_t: Scenario,
@@ -818,12 +841,12 @@ def build_sim(
         The participation mask is computed HERE, inside the scan, from the
         live ``signals`` (the policy decides who trains this round); the
         scenario's own ``participation`` schedule is the availability base.
-        Returns (w_loc, new_signals, mask, bias) — participants' trailing
-        loss / update-norm signals are refreshed, everyone else keeps the
-        score they last earned.  Signals reduce over the per-leaf VIEWS of
-        the full rows, never the raw (possibly padded) rows, so their
-        reduction grouping — and the selection trajectory — is independent
-        of ``model_shards``.
+        Returns (w_loc, new_signals, mask, bias, loss_in) — participants'
+        trailing loss / update-norm signals are refreshed, everyone else
+        keeps the score they last earned.  Signals reduce over the
+        per-leaf VIEWS of the full rows, never the raw (possibly padded)
+        rows, so their reduction grouping — and the selection trajectory —
+        is independent of ``model_shards``.
         """
         base = scenario_t.participation
         base = (jnp.ones((n,), jnp.float32) if base is None
@@ -842,7 +865,7 @@ def build_sim(
                 scenario_t.policy_id, base, p, scenario_t.rho[:n, :n],
                 scenario_t.select_frac, scenario_t.compress_ratio,
             )
-        new_loc, trained, old_full, bias = _round_core(
+        new_loc, trained, old_full, bias, loss_in = _round_core(
             w_loc, rng, scenario_t, mask, ratio_override
         )
         out_full = _full_rows(new_loc)
@@ -860,7 +883,7 @@ def build_sim(
             loss=jnp.where(mask > 0, train_loss(b_out), signals.loss),
             upd_norm=jnp.where(mask > 0, upd, signals.upd_norm),
         )
-        return new_loc, new_signals, mask, bias
+        return new_loc, new_signals, mask, bias, loss_in
 
     def round_step(state: dict, rng: jax.Array, scenario: Scenario):
         """One pure D-FL round: local training + traced-protocol exchange.
@@ -899,7 +922,7 @@ def build_sim(
         if part is not None:
             part = part[:n]
         w_seg, spec, mp = protocols._to_segments(state["params"], seg_len)
-        new_seg, _t, _o, bias = _round_core(w_seg, rng, scenario, part)
+        new_seg, _t, _o, bias, _l = _round_core(w_seg, rng, scenario, part)
         with jax.named_scope(_EVAL_SCOPE):
             metrics = {
                 "acc": evaluate(new_seg),
@@ -930,19 +953,25 @@ def build_sim(
         key, k_round = jax.random.split(state["key"])
         sc_t = scenario.at_round(t)
         if scenario.policy_id is not None:
-            w, sig, mask, bias = _advance_closed(
+            w, sig, mask, bias, loss_in = _advance_closed(
                 state["w"], k_round, sc_t, state["sig"]
             )
             return ({"key": key, "w": w, "sig": sig},
-                    {"bias": bias, "selected": mask})
-        w, bias = _advance(state["w"], k_round, sc_t)
-        return {"key": key, "w": w}, {"bias": bias}
+                    {"bias": bias, "selected": mask, "loss_in": loss_in})
+        w, bias, loss_in = _advance(state["w"], k_round, sc_t)
+        return {"key": key, "w": w}, {"bias": bias, "loss_in": loss_in}
 
     def advance_chunk(state: dict, scenario: Scenario, c: jnp.ndarray):
         """Advance chunk ``c`` (= rounds c*k .. (c+1)*k - 1, k=eval_every).
 
         Returns (state, metrics-row): per-round ``bias`` (and ``selected``
-        for closed-loop scenarios) plus chunk-end ``acc`` / ``loss``.
+        for closed-loop scenarios), chunk-end ``acc``, ``loss_in`` and
+        ``loss_end``.  ``loss_in`` is the train loss on the state the
+        chunk ENTERED with, from its first local epoch (`local_train`):
+        chunk c's ``loss`` is taken from chunk c+1's ``loss_in``, so only
+        the last chunk computes its own, as ``loss_end`` (a `lax.cond` on
+        the unbatched chunk index; zeros on every other chunk).
+        `chunk_metrics` builds ``loss`` from the stacked rows.
         ``eval_every == 1`` advances the single round inline — no inner
         scan — so the per-round program is exactly the unchunked one.
         """
@@ -954,9 +983,14 @@ def build_sim(
                 lambda s, t: _round(s, t, scenario),
                 state, c * eval_every + jnp.arange(eval_every),
             )
+            extras["loss_in"] = extras["loss_in"][0]
         full = _full_rows(state["w"])
         with jax.named_scope(_EVAL_SCOPE):
-            metrics = {"acc": evaluate(full), "loss": train_loss(full),
+            loss_end = jax.lax.cond(
+                c == n_chunks - 1, train_loss,
+                lambda rows: jnp.zeros((n,), extras["loss_in"].dtype), full,
+            )
+            metrics = {"acc": evaluate(full), "loss_end": loss_end,
                        **extras}
         return state, metrics
 
@@ -968,15 +1002,11 @@ def build_sim(
     def run_scenario(scenario: Scenario) -> dict:
         scenario = scenario.prepare()
         state = _scan_init(scenario, jax.random.PRNGKey(scenario.seed))
-        _, metrics = jax.lax.scan(
+        _, rows = jax.lax.scan(
             lambda s, c: advance_chunk(s, scenario, c),
             state, jnp.arange(n_chunks),
         )
-        if eval_every > 1:
-            metrics["bias"] = metrics["bias"].reshape(-1)      # (n_rounds,)
-            if "selected" in metrics:
-                metrics["selected"] = metrics["selected"].reshape(-1, n)
-        return metrics
+        return chunk_metrics(rows)
 
     return SimPrograms(
         round_step=_float32_matmuls(round_step),
@@ -994,6 +1024,26 @@ def build_sim(
         seg_len=seg_len,
         bits_per_value=bits_per_value,
     )
+
+
+def chunk_metrics(rows: dict) -> dict:
+    """Stacked `advance_chunk` rows -> the `run_scenario` metrics dict.
+
+    Chunk c's ``loss`` is chunk c+1's ``loss_in`` (the next chunk's first
+    local-epoch forward, on the same parameters and shard), and the last
+    chunk's is its own ``loss_end``.  Per-round ``bias`` / ``selected``
+    flatten across chunks to ``(n_rounds, ...)``.  Works on traced arrays
+    (`run_scenario`) and on host numpy rows
+    (`checkpoint.run_resumable`) alike.
+    """
+    xp = np if isinstance(rows["loss_in"], np.ndarray) else jnp
+    out = {k: v for k, v in rows.items() if k not in ("loss_in", "loss_end")}
+    out["loss"] = xp.concatenate([rows["loss_in"][1:], rows["loss_end"][-1:]])
+    out["bias"] = rows["bias"].reshape(-1)
+    if "selected" in rows:
+        sel = rows["selected"]
+        out["selected"] = sel.reshape(-1, sel.shape[-1])
+    return out
 
 
 def _float32_matmuls(fn: Callable) -> Callable:
