@@ -3,7 +3,7 @@
 Drives a synthetic Poisson arrival process (DESIGN.md §11) over a pool of
 single-scenario requests (3 topologies x {ra, aayg}), measures
 requests/sec and p50/p99 request latency in a steady-state phase (after a
-priming pass that doubles as the bit-identity check against a direct
+priming pass that doubles as the equivalence check against a direct
 `GridRunner.run` of the same scenarios), and writes the snapshot to
 ``BENCH_serve.json`` (benchmarks/common.write_bench).
 
@@ -52,19 +52,20 @@ def main() -> None:
     compiled = server.warmup(*pool, scenarios.ScenarioGrid.concat(*pool))
     t_warm = time.monotonic() - t0
 
-    # Direct warm-runner reference for the bit-identity contract.
+    # Direct warm-runner reference for the equivalence contract.
     ref_runner = scenarios.GridRunner(init, apply_fn, data, cfg)
     refs = [ref_runner.run(g) for g in pool]
 
     with server:
         # Priming burst (back-to-back submits coalesce) + correctness:
-        # batched serving must be bit-identical to the direct runner.
+        # batched serving must match the direct runner within float32
+        # round-off (DESIGN.md §16).
         got = server.serve(pool)
         mismatched = [
             g.labels[0]
             for g, r in zip(got, refs)
             if not all(
-                np.array_equal(np.asarray(a), np.asarray(b), equal_nan=True)
+                np.allclose(a, b, rtol=1e-5, atol=1e-6, equal_nan=True)
                 for a, b in ((g.acc, r.acc), (g.loss, r.loss),
                              (g.bias, r.bias))
             )
@@ -102,7 +103,7 @@ def main() -> None:
         "warmup_programs": compiled,
         "warmup_s": t_warm,
         "tiny": tiny,
-        "bit_identical": not mismatched,
+        "matches_direct": not mismatched,
     }
     common.emit(
         "serve/open_loop", row["us_per_call"],
@@ -110,7 +111,7 @@ def main() -> None:
         f"p50_s={row['latency_p50_s']:.4f};p99_s={row['latency_p99_s']:.4f};"
         f"fill={row['batch_fill_mean']:.3f};"
         f"cache_hit={row['cache_hit']};cache_miss={row['cache_miss']};"
-        f"bit_identical={row['bit_identical']}",
+        f"matches_direct={row['matches_direct']}",
     )
     common.write_bench("serve", [row])
     if mismatched:

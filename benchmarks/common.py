@@ -177,5 +177,5 @@ def standard_fl(n_rounds=15, protocol="ra", mode="ra_normalized",
                        aayg_mixes=aayg_mixes, seed=seed,
                        protocol=protocol, mode=mode)
     init, apply_fn = standard_model()
-    res = simulator.run(init, apply_fn, data, net, cfg)
+    res = scenarios.simulate(init, apply_fn, data, net, cfg)
     return res, net, data

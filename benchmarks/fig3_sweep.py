@@ -72,9 +72,10 @@ def main() -> None:
     runner.run(grid)
     t_warm = time.time() - t0
 
-    # Baseline 1: per-scenario dispatch of the same compiled scalar program.
+    # Baseline 1: per-scenario dispatch, each row as a one-point grid.
     t0 = time.time()
-    runner.run_sequential(grid)
+    for i in range(len(grid)):
+        runner.run(grid.take([i]))
     t_seq = time.time() - t0
 
     # Baseline 2: seed-code behavior — static protocol/mode configs forced a

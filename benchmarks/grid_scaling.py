@@ -3,7 +3,8 @@
 Reruns the fig3 sweep (27 scenarios: 3 densities x 3 packet lengths x 3
 protocol rows) through `GridRunner.run(devices=...)` on 1, 2, 4, and 8
 devices and reports warm-dispatch throughput per device count, verifying
-each sharded result bit-identical to the single-device reference.
+each sharded result equal (within float32 round-off) to the
+single-device reference.
 
 Device counts are forced host (CPU) devices unless XLA_FLAGS is already
 set (on a real accelerator, export XLA_FLAGS= and the machine's devices
@@ -52,20 +53,20 @@ def main() -> None:
         t0 = time.time()
         runner.run(grid, devices=devs)
         t_warm = time.time() - t0
-        # equal_nan: bias is NaN for non-R&A rows (NaN == NaN bitwise here).
-        identical = all(
-            np.array_equal(np.asarray(got), np.asarray(want), equal_nan=True)
+        # equal_nan: bias is NaN for non-R&A rows.
+        matches = all(
+            np.allclose(got, want, rtol=1e-5, atol=1e-6, equal_nan=True)
             for got, want in ((res.acc, ref.acc), (res.loss, ref.loss),
                               (res.bias, ref.bias))
         )
-        if not identical:
+        if not matches:
             mismatched.append(d)
         common.emit(
             f"grid_scaling/d{d}", t_warm * 1e6 / len(grid),
             f"devices={d};scenarios={len(grid)};"
             f"scenarios_per_s={len(grid) / max(t_warm, 1e-9):.2f};"
             f"cold_s={t_cold:.2f};warm_s={t_warm:.2f};"
-            f"bit_identical={identical}",
+            f"matches_direct={matches}",
         )
     if mismatched:
         raise SystemExit(

@@ -7,11 +7,11 @@ replica carrying the traffic mid-stream, and reports throughput and
 client-observed latency for three windows — before the kill, during it
 (the failover transient: retries, breaker trip, re-route), and after
 (steady state on the survivors).  Every delivered result is checked
-bit-identical to a direct `GridRunner.run` of the same scenarios; any
-mismatch or undelivered request fails the run.  Rows land in
-``BENCH_serve_failover.json``; the headline acceptance number is a
-FINITE post-failover p99 — the fleet keeps serving correctly with a
-replica dead.
+equal (within float32 round-off) to a direct `GridRunner.run` of the
+same scenarios; any mismatch or undelivered request fails the run.
+Rows land in ``BENCH_serve_failover.json``; the headline acceptance
+number is a FINITE post-failover p99 — the fleet keeps serving correctly
+with a replica dead.
 
 Tiny mode for CI smoke: ``REPRO_BENCH_TINY=1``.
 
@@ -61,7 +61,7 @@ def _phase(rt, pool, refs, n_requests, rate, rng, *, kill=None):
             continue
         ref = refs[i % len(pool)]
         if not all(
-            np.array_equal(np.asarray(a), np.asarray(b), equal_nan=True)
+            np.allclose(a, b, rtol=1e-5, atol=1e-6, equal_nan=True)
             for a, b in ((got.acc, ref.acc), (got.loss, ref.loss),
                          (got.bias, ref.bias))
         ):
@@ -123,7 +123,7 @@ def main() -> None:
         # Priming pass: absorbs any residual first-dispatch compiles so
         # the three measured windows are comparable.
         for got, ref in zip(rt.serve(pool), refs):
-            if not np.array_equal(np.asarray(got.acc), np.asarray(ref.acc)):
+            if not np.allclose(got.acc, ref.acc, rtol=1e-5, atol=1e-6):
                 problems.append(("prime", "mismatch", "priming pass"))
         phases = (
             ("before", None),
@@ -153,7 +153,7 @@ def main() -> None:
                 "requests_per_s": per_phase / max(dt, 1e-9),
                 "latency_p50_s": p50,
                 "latency_p99_s": p99,
-                "bit_identical": not mismatched,
+                "matches_direct": not mismatched,
                 "warmup_programs": compiled,
                 "warmup_s": t_warm,
                 "tiny": tiny,
@@ -164,7 +164,7 @@ def main() -> None:
                 f"phase={phase_name};req_per_s={row['requests_per_s']:.2f};"
                 f"p50_s={p50:.4f};p99_s={p99:.4f};"
                 f"delivered={row['delivered']}/{per_phase};"
-                f"bit_identical={row['bit_identical']}",
+                f"matches_direct={row['matches_direct']}",
             )
         snap = rt.tracker.snapshot()
     rows.append({
@@ -181,7 +181,7 @@ def main() -> None:
     common.write_bench("serve_failover", rows)
 
     # Acceptance: recovery is real — the post-failover window delivered
-    # everything with a finite p99, bit-identically.
+    # everything with a finite p99, equal to direct runs.
     after = rows[2]
     if not (np.isfinite(after["latency_p99_s"])
             and after["delivered"] == per_phase):

@@ -4,9 +4,9 @@ Drives the same mixed-priority open-loop Poisson load (DESIGN.md §12)
 through a `ScenarioServer` sharded over 1, 2, 4, and 8 devices
 (`devices=jax.devices()[:d]` — the ('grid',) mesh dispatch path) and
 reports requests/sec, p50/p99 latency, and batch fill per device count,
-verifying every sharded result bit-identical to a direct single-device
-`GridRunner.run` of the same scenarios.  Rows land in
-``BENCH_serve_scaling.json`` (benchmarks/common.write_bench).
+verifying every sharded result equal (within float32 round-off) to a
+direct single-device `GridRunner.run` of the same scenarios.  Rows land
+in ``BENCH_serve_scaling.json`` (benchmarks/common.write_bench).
 
 Device counts are forced host (CPU) devices unless XLA_FLAGS is already
 set (on a real accelerator, export XLA_FLAGS= and the machine's devices
@@ -63,7 +63,7 @@ def main() -> None:
         for lbl, net in nets
         for proto in ("ra", "aayg")
     ]
-    # Single-device reference for the bit-identity contract: EVERY mesh
+    # Single-device reference for the equivalence contract: EVERY mesh
     # width must reproduce these results exactly.
     ref_runner = scenarios.GridRunner(init, apply_fn, data, cfg)
     refs = [ref_runner.run(g) for g in pool]
@@ -85,13 +85,13 @@ def main() -> None:
         compiled = server.warmup(*pool, scenarios.ScenarioGrid.concat(*pool))
         t_warm = time.monotonic() - t0
         with server:
-            # Priming pass doubles as the per-mesh bit-identity check.
+            # Priming pass doubles as the per-mesh equivalence check.
             got = server.serve(pool)
             bad = [
                 g.labels[0]
                 for g, r in zip(got, refs)
                 if not all(
-                    np.array_equal(np.asarray(a), np.asarray(b),
+                    np.allclose(a, b, rtol=1e-5, atol=1e-6,
                                    equal_nan=True)
                     for a, b in ((g.acc, r.acc), (g.loss, r.loss),
                                  (g.bias, r.bias))
@@ -131,7 +131,7 @@ def main() -> None:
             "warmup_programs": compiled,
             "warmup_s": t_warm,
             "tiny": tiny,
-            "bit_identical": not bad,
+            "matches_direct": not bad,
         }
         rows.append(row)
         common.emit(
@@ -140,7 +140,7 @@ def main() -> None:
             f"p50_s={row['latency_p50_s']:.4f};"
             f"p99_s={row['latency_p99_s']:.4f};"
             f"fill={row['batch_fill_mean']:.3f};"
-            f"bit_identical={row['bit_identical']}",
+            f"matches_direct={row['matches_direct']}",
         )
     common.write_bench("serve_scaling", rows)
     if mismatched:

@@ -11,7 +11,7 @@ import numpy as np
 
 from repro.core import routing, topology
 from repro.data import synthetic
-from repro.fl import simulator
+from repro.fl import scenarios, simulator
 from repro.models import smallnets
 
 N_ROUNDS = 15
@@ -43,7 +43,8 @@ def main() -> None:
     ]:
         cfg = simulator.SimConfig(protocol=proto, mode=mode, n_rounds=N_ROUNDS,
                                   local_epochs=3, seg_len=256)
-        res = simulator.run(init, smallnets.apply_mlp_clf, data, net, cfg)
+        res = scenarios.simulate(init, smallnets.apply_mlp_clf, data, net,
+                                 cfg)
         print(f"{label:48s} acc={res.mean_acc[-1]:.3f} "
               f"spread={res.acc_per_client[-1].std():.3f}")
 

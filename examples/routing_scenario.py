@@ -11,7 +11,7 @@ import numpy as np
 
 from repro.core import convergence, routing, topology
 from repro.data import synthetic
-from repro.fl import simulator
+from repro.fl import scenarios, simulator
 from repro.models import smallnets
 
 
@@ -30,7 +30,8 @@ def main() -> None:
         obj = float(convergence.routing_objective(p, rho))
         cfg = simulator.SimConfig(protocol="ra", n_rounds=12, local_epochs=3,
                                   seg_len=256)
-        res = simulator.run(init, smallnets.apply_mlp_clf, data, net, cfg)
+        res = scenarios.simulate(init, smallnets.apply_mlp_clf, data, net,
+                                 cfg)
         print(f"relays={n_relays:<3d} (V={net.n_nodes:<3d})            "
               f"{obj:18.5f} {res.mean_acc[-1]:10.3f}")
 

@@ -4,7 +4,8 @@ Builds a grid crossing two packet lengths x three protocol rows x two seeds
 (12 scenarios) and runs the whole thing through ONE vmapped, jitted training
 loop — the same engine the figure benchmarks use — then prints a small
 per-scenario table, the dispatch-cost comparison, and a sharded dispatch
-over every visible device (`devices=jax.devices()`; results bit-identical).
+over every visible device (`devices=jax.devices()`; results equal within
+float32 round-off, DESIGN.md §16).
 
 Run:  PYTHONPATH=src python examples/sweep_grid.py
 To see real grid sharding on CPU, force host devices first:
@@ -66,8 +67,10 @@ def main() -> None:
     runner.run(grid2)
     t_warm = time.time() - t0
 
+    # Per-scenario baseline: each row dispatched as its own one-point grid.
     t0 = time.time()
-    runner.run_sequential(grid)
+    for i in range(len(grid)):
+        runner.run(grid.take([i]))
     t_seq = time.time() - t0
 
     # Sharded dispatch: the same grid spread over every visible device
@@ -76,13 +79,14 @@ def main() -> None:
     t0 = time.time()
     sharded = runner.run(grid, devices=devs)
     t_sharded = time.time() - t0
-    assert np.array_equal(np.asarray(sharded.acc), np.asarray(res.acc))
+    # Another program of the same math: equal within float32 round-off.
+    np.testing.assert_allclose(sharded.acc, res.acc, rtol=1e-5, atol=1e-6)
 
     print(f"\nbatched, cold (compile + dispatch):    {t_batched:6.2f} s")
     print(f"batched, warm (new seeds, no compile): {t_warm:6.2f} s")
     print(f"per-scenario loop (incl. compile):     {t_seq:6.2f} s")
     print(f"sharded over {len(devs)} device(s), cold:      {t_sharded:6.2f} s"
-          f"  (bit-identical)")
+          f"  (same results within float32 round-off)")
 
 
 if __name__ == "__main__":

@@ -6,9 +6,9 @@ Multi-Hop Wireless Networks", arXiv 2603.15188) makes WHAT is transmitted a
 design axis alongside WHERE it is routed.  This module puts a codec between
 local training and delivery:
 
-  * ``none``  — the neutral codec: every segment ships untouched.  Bitwise
-                identical to the pre-codec exchange path (the compatibility
-                baseline every test tier pins).
+  * ``none``  — the neutral codec: every segment ships untouched.  The
+                semantics of the pre-codec exchange path, equal to it
+                within float32 round-off (DESIGN.md §16).
   * ``topk``  — top-k segment sparsification: each client transmits only
                 its ``ceil(ratio * S)`` largest-L2-norm segments.  Pruned
                 segments are NEVER SENT — they are neither an error nor a

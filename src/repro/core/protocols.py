@@ -93,12 +93,13 @@ def ra_round_seg(
     GLOBAL segment count) the success mask is sampled at the FULL
     (N, N, S) shape from the shared ``key`` and then sliced to this
     shard's ``[seg_start, seg_start + L_local)`` window — every shard
-    draws the same uniforms, so the per-global-segment masks (and with
-    them the aggregated model) are bitwise identical to the unsharded
-    run.  The returned ``e`` is the FULL (participation-masked) mask, so
-    the bias diagnostic reduces over every global segment on every shard
-    (replicated, equal to the unsharded value).  ``seg_total=None`` (the
-    default) keeps the exact single-shard trace.
+    draws the same uniforms, so the per-global-segment masks are bitwise
+    identical to the unsharded run's (and with them the aggregated
+    model has the unsharded semantics).  The returned ``e`` is the FULL
+    (participation-masked) mask, so the bias diagnostic reduces over
+    every global segment on every shard (replicated, equal to the
+    unsharded value).  ``seg_total=None`` (the default) keeps the exact
+    single-shard trace.
     """
     n, l = w_seg.shape[0], w_seg.shape[1]
     e = errors.sample_success(key, rho, l if seg_total is None else seg_total,

@@ -14,7 +14,8 @@ protocol ids — a grid sweeping policies stays ONE vmapped/sharded dispatch):
 
   * ``uniform``    — the neutral policy: return the scenario's precomputed
                      participation mask unchanged (all-ones when absent).
-                     Bitwise identical to the PR-3 open-loop path.
+                     The open-loop path's masks exactly, and its
+                     trajectories within float32 round-off (DESIGN.md §16).
   * ``loss``       — loss-proportional importance: the k clients with the
                      largest trailing train loss participate (they need
                      training the most).

@@ -3,12 +3,16 @@ sweeps in a single XLA dispatch, optionally sharded across devices.
 
 The paper's headline results (Figs. 2, 3, 8, 9; Table III) are sweeps over
 packet error rates, relay counts, protocols, and seeds.  Because the round
-loop (`repro.fl.simulator.round_step`) is a pure jitted function of a
-`Scenario` whose parameters are all traced arrays, a whole grid of scenarios
-compiles to ONE program and runs as ONE dispatch:
+loop (`repro.fl.simulator.SimPrograms.run_scenario`) is a pure jitted
+function of a `Scenario` whose parameters are all traced arrays, a whole
+grid of scenarios compiles to ONE program and runs as ONE dispatch:
 
     grid = ScenarioGrid.product(networks=[...], protocols=[...], seeds=[...])
     res = run_grid(init_fn, apply_fn, data, grid, cfg)   # (G, rounds, N)
+
+A single scenario is the one-point grid (`simulate`), so there is one
+program per semantics: a scalar run and a grid row of batch 1 are the
+same compiled executable.
 
 Scenario axes:
 
@@ -59,8 +63,8 @@ dimension:
                       between local training and the exchange, the
                       codec's per-segment transmit mask composes with the
                       channel success mask, and a ratio x protocol x PER
-                      sweep is still ONE dispatch.  The ``none`` codec is
-                      bitwise identical to a codec-free grid.
+                      sweep is still ONE dispatch.  The ``none`` codec
+                      has the semantics of a codec-free grid.
 
 Grid leaves are kept HOST-SIDE (numpy): the per-dispatch uniform-field
 hoisting test then costs no device sync, and arrays only move to devices
@@ -73,15 +77,16 @@ vmapped round loop on its slice of the batch, with NO cross-device
 collectives in the hot loop (scenarios are independent).  Batches that do
 not divide the device count are padded with routing-neutral filler
 scenarios (every node isolated — the same machinery that pads small
-networks) and unpadded on return; results are bit-identical to the
-single-device path:
+networks) and unpadded on return:
 
     res = run_grid(init_fn, apply_fn, data, grid, cfg, devices=jax.devices())
 
-`run_sequential` runs the same grid through the same compiled scalar program
-one scenario at a time — the per-scenario-dispatch baseline for timing
-comparisons (see benchmarks/fig3_sweep.py); `benchmarks/grid_scaling.py`
-measures scenarios/sec vs device count through the sharded path.
+A sharded, padded or differently batched dispatch is a different XLA
+program of the same float32 math: its results equal the single-device
+path's within float32 round-off, not bit for bit.  DESIGN.md §16 states
+which comparisons are bitwise and which carry that tolerance.
+`benchmarks/grid_scaling.py` measures scenarios/sec vs device count
+through the sharded path.
 
 Public API
 ----------
@@ -92,7 +97,7 @@ Public API
                                   the time axis, recomputes rho)
   sampling_schedule(...)          (T, N) per-round client-sampling mask
   run_grid(..., devices=None)     one-shot batched (optionally sharded) run
-  run_sequential(...)             per-scenario-dispatch baseline
+  simulate(...)                   one scenario: the one-point grid
   GridRunner(..., devices=None)   warm-program server for repeated grids
                                   (+ tracker= / max_cached_programs= /
                                   warmup() / validate() — DESIGN.md §11)
@@ -206,7 +211,7 @@ def sampling_schedule(n_clients: int, n_rounds: int, fraction: float, *,
 
     Each round independently samples ``ceil(fraction * n_clients)`` clients
     without replacement (at least one).  ``fraction=1`` yields the all-ones
-    mask (bitwise equivalent to full participation).  Deterministic in
+    mask (the semantics of full participation).  Deterministic in
     ``seed``.
     """
     if not 0.0 < fraction <= 1.0:
@@ -422,13 +427,14 @@ class ScenarioGrid:
             pol, frac = s.policy_id, s.select_frac
             if any_policy and pol is None:
                 # Neutral fill-in: the uniform policy IS the open-loop path
-                # (frac unread), so policy-free grids join bitwise intact.
+                # (frac unread), so policy-free grids keep their semantics.
                 pol = np.zeros((len(g),), np.int32)
                 frac = np.ones((len(g),), np.float32)
             cod, ratio = s.codec_id, s.compress_ratio
             if any_codec and cod is None:
-                # Neutral fill-in: the `none` codec at ratio 1 is bitwise
-                # the codec-free exchange, so codec-free grids join intact.
+                # Neutral fill-in: the `none` codec at ratio 1 is the
+                # codec-free exchange, so codec-free grids keep their
+                # semantics.
                 cod = np.full((len(g),), compression.CODEC_IDS["none"],
                               np.int32)
                 ratio = np.ones((len(g),), np.float32)
@@ -494,7 +500,7 @@ class ScenarioGrid:
             of value bits under ``quant``; unread by ``none``).  Encoded
             between local training and the exchange (DESIGN.md §15); the
             ``none`` codec traces a transmit-everything mask whose
-            results are bitwise those of a codec-free grid.
+            results are those of a codec-free grid (DESIGN.md §16).
           local_epochs: optional (N,) per-client epoch vector shared by
             every grid point (values clip to the SimConfig bound).
           aggregator: C-FL star center (shared; only read by cfl scenarios).
@@ -1141,7 +1147,6 @@ class GridRunner:
         # device buffers never outlive one call (no double-buffering of the
         # round-loop state against its inputs).  No-op on CPU.
         self._donate = simulator.donate_kwargs()
-        self._scalar = jax.jit(self.sim.run_scenario, **self._donate)
 
     def _sim_for(self, model_shards: int) -> simulator.SimPrograms:
         sim = self._sims.get(model_shards)
@@ -1191,10 +1196,9 @@ class GridRunner:
         sub-batch over a ``('grid',)`` mesh via shard_map: the batch is
         padded to a multiple of the device count with routing-neutral
         filler scenarios, every device runs the vmapped loop on its slice
-        (no collectives), and results are gathered + unpadded —
-        bit-identical to the single-device path.  Defaults to the
-        runner's ``devices``; an explicit ``devices=None`` forces the
-        single-device vmap path regardless of the runner default.
+        (no collectives), and results are gathered + unpadded.  Defaults
+        to the runner's ``devices``; an explicit ``devices=None`` forces
+        the single-device vmap path regardless of the runner default.
 
         ``pad_to=`` declares warm batch-size buckets (an int or a
         sequence): each (protocol, mode) sub-batch is padded with
@@ -1202,8 +1206,14 @@ class GridRunner:
         fits (see `_bucket_target`), so a serving tier dispatching
         variable-size coalesced batches reuses a BOUNDED family of
         compiled programs instead of compiling per arrival pattern.
-        Filler rows are dropped on unpad — results are bit-identical to
-        the unpadded dispatch.
+        Filler rows are dropped on unpad.
+
+        A scenario's result does not depend on how it was dispatched, up
+        to float32 round-off: a padded, sharded or differently sized batch
+        is a different XLA program of the same math, which may reassociate
+        sums, so its results match the plain dispatch's within the
+        tolerance of DESIGN.md §16, not bit for bit.  The same grid through
+        the same compiled program is bitwise reproducible.
 
         ``validate=False`` skips admission validation (`validate_grid`)
         for callers that already validated at submission time.
@@ -1367,15 +1377,6 @@ class GridRunner:
 
         return self.programs.lookup(sig, build), args
 
-    def run_sequential(self, grid: ScenarioGrid) -> GridResult:
-        """Per-scenario-dispatch baseline: the compiled scalar program,
-        called once per grid row.  Semantically identical to `run()` (same
-        pure program, no vmap) — the timing baseline for dispatch-overhead
-        comparisons and equivalence tests."""
-        metrics = [self._scalar(grid.scenario(i)) for i in range(len(grid))]
-        stacked = jax.tree.map(lambda *leaves: jnp.stack(leaves), *metrics)
-        return _metrics_to_grid_result(stacked, grid.labels)
-
 
 def run_grid(
     init_fn: Callable[[jax.Array], Pytree],
@@ -1393,20 +1394,32 @@ def run_grid(
     `cfg` supplies the static (shared) knobs: seg_len, local_epochs,
     n_rounds, aayg_mixes.  Per-scenario knobs live in the grid.
     ``devices=`` / ``sharding=`` shard the grid axis across a device mesh
-    (bit-identical results; see the module docstring and DESIGN.md §7).
+    (the same semantics, equal within float32 round-off; see the module
+    docstring and DESIGN.md §7, §16).
     """
     runner = GridRunner(init_fn, apply_fn, data, cfg)
     return runner.run(grid, group_by_protocol=group_by_protocol,
                       devices=devices, sharding=sharding)
 
 
-def run_sequential(
+def simulate(
     init_fn: Callable[[jax.Array], Pytree],
     apply_fn: Callable[[Pytree, jnp.ndarray], jnp.ndarray],
     data: FederatedDataset,
-    grid: ScenarioGrid,
+    net: topology.Network,
     cfg: simulator.SimConfig,
-) -> GridResult:
-    """One-shot per-scenario-dispatch baseline (see GridRunner)."""
-    runner = GridRunner(init_fn, apply_fn, data, cfg)
-    return runner.run_sequential(grid)
+) -> simulator.SimResult:
+    """One scenario of ``cfg`` on ``net``, run as the one-point grid.
+
+    The grid holds ``cfg``'s protocol, mode, seed, lr and C-FL aggregator
+    (and its codec, when set), so a scalar run and a one-point `run_grid`
+    of the same scenario are the same compiled program, bit for bit.
+    """
+    codecs = (None if cfg.codec is None
+              else [(cfg.codec, cfg.codec, cfg.compress_ratio)])
+    grid = ScenarioGrid.product(
+        networks=[("net", net)], protocols=[(cfg.protocol, cfg.mode)],
+        seeds=[cfg.seed], lrs=(cfg.lr,), aggregator=cfg.cfl_aggregator,
+        codecs=codecs,
+    )
+    return run_grid(init_fn, apply_fn, data, grid, cfg).result(0)

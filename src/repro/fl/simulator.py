@@ -27,8 +27,9 @@ of grid points —
     per-client values are clipped to it).
 
 All three default to the static behavior (None / rank-2 ``link_eps``), in
-which case `run_scenario` traces the EXACT pre-dynamic program — static
-scenarios stay bit-identical.
+which case `run_scenario` traces the exact pre-dynamic program; a neutral
+dynamic axis (T=1 schedule, all-ones mask) is the same semantics in a
+different program, equal within float32 round-off (DESIGN.md §16).
 
 Closed-loop selection (DESIGN.md §10): a `Scenario` may additionally carry
 a ``policy_id`` / ``select_frac`` pair (`core.selection.POLICY_IDS`); the
@@ -37,7 +38,8 @@ live per-client signals (trailing train loss + local update norms) carried
 in the scan state — dispatched by `lax.switch` like protocols, so a grid
 sweeping policies stays one vmapped/sharded dispatch.  ``policy_id=None``
 (the default) traces the exact pre-policy program; the ``uniform`` policy
-reproduces the open-loop participation path bitwise.
+has the open-loop participation path's semantics (the same masks, and
+trajectories equal within float32 round-off, DESIGN.md §16).
 
 Segment-native state + model-axis sharding (DESIGN.md §13): the round
 scan carries the paper's exchange representation — client-stacked segment
@@ -55,7 +57,7 @@ Static compute knobs (DESIGN.md §9): `SimConfig.agg_impl` selects the
 aggregation substrate (jnp reference vs the fused/batched Pallas kernel;
 auto = native Pallas on TPU only), `eval_every=k` thins per-round metric
 evaluation to every k-th round (static ``(n_rounds // k,)`` metric axis;
-the trained trajectory is bitwise unchanged), and `track_bias=False`
+the trained trajectory is unchanged), and `track_bias=False`
 drops the R&A ||Lambda||^2 diagnostic from the hot loop.
 
 The simulator is model-agnostic: pass any (init, apply) pair from
@@ -67,16 +69,18 @@ Public API
   Scenario / make_scenario  one grid point, all fields traced arrays
   Scenario.at_round(t)      per-round view of a dynamic scenario
   build_sim(...)            bind (init, apply, data, statics) -> SimPrograms
-  SimPrograms.round_step    (state, rng, scenario) -> (state, metrics)
   SimPrograms.run_scenario  scenario -> metrics dict (scanned n_rounds)
+  SimPrograms.init_scan / advance_chunk   the round API, a chunk at a time
   chunk_metrics             stacked advance_chunk rows -> metrics dict
-  run / simulate            scalar one-scenario entry point -> SimResult
-  metrics_to_result         metrics dict -> SimResult
   ROUND_SCOPES              the round's phases as `jax.named_scope`s
 
-Purity contract: `round_step` and `run_scenario` are side-effect free
-functions of their arguments plus the statics bound by `build_sim` —
-jit/vmap/shard_map-safe by construction (see tests/test_scenarios.py).
+The one-scenario entry point is `repro.fl.scenarios.simulate`: the
+one-point grid, so a scalar run IS the grid program.
+
+Purity contract: `run_scenario`, `init_scan` and `advance_chunk` are
+side-effect free functions of their arguments plus the statics bound by
+`build_sim` — jit/vmap/shard_map-safe by construction (see
+tests/test_scenarios.py).
 """
 from __future__ import annotations
 
@@ -102,7 +106,7 @@ MODEL_AXIS = "model"
 # fold_in tag deriving the codec's private key from the round key.  The
 # round key itself still feeds the exchange UNTOUCHED, so configuring
 # codec="none" draws the same channel randomness as no codec at all —
-# load-bearing for the neutral codec's bitwise guarantee (DESIGN.md §15).
+# load-bearing for the neutral codec's equivalence (DESIGN.md §15).
 _CODEC_KEY_TAG = 0x434F4445  # "CODE"
 
 # The round's phases as `jax.named_scope`s: every op of local training,
@@ -122,13 +126,15 @@ class PacketLengthMismatchWarning(UserWarning):
 def _fusion_barrier(tree: Pytree) -> Pytree:
     """`lax.optimization_barrier` that composes with vmap (identity values).
 
-    The closed-loop signal refresh reduces over the same tensors the round
-    math produces; without a barrier those extra consumers perturb XLA's
-    fusion choices and break the uniform policy's REQUIRED bit-identity
-    with the open-loop path (~1e-7 drift — the same fragility DESIGN.md §9
-    records for `bias_sq_norm_fused`).  `optimization_barrier` has no
-    batching rule, so `run_grid`'s vmap needs this custom one: the barrier
-    is elementwise identity, hence batching passes straight through.
+    `local_train` takes each client's leaves out of its segment row behind
+    this barrier every epoch.  It holds correctness, not round-off: on a
+    TPU v5e the gradient taken through the row views without it left
+    ResNet-18-GN at about twice the plain reference's loss after one step
+    (the cause is not known), and it gives the leaves buffers of their own,
+    without which the CharRNN program's temporaries grow by 0.9 GB.
+    `optimization_barrier` has no batching rule, so `run_grid`'s vmap needs
+    this custom one: the barrier is elementwise identity, hence batching
+    passes straight through.
     """
     return jax.lax.optimization_barrier(tree)
 
@@ -254,7 +260,7 @@ class Scenario(NamedTuple):
 
         Time-leaved fields are sliced at ``t`` modulo their own schedule
         length (a T=1 schedule is therefore exactly a static scenario);
-        already-static fields pass through untouched.  `round_step`
+        already-static fields pass through untouched.  The round body
         consumes these views — it never sees a time axis.
         """
         s = self
@@ -368,7 +374,7 @@ def make_scenario(
     name; defaults to ``cfg.codec``) encodes the exchange — top-k segment
     sparsification or stochastic quantization at ``compress_ratio``
     (defaults to ``cfg.compress_ratio``); codec "none" is the traced
-    neutral point, bit-identical to no codec at all (DESIGN.md §15).
+    neutral point, the semantics of no codec at all (DESIGN.md §15).
     """
     codec = cfg.codec if codec is None else codec
     if codec is not None and codec not in compression.CODEC_IDS:
@@ -437,10 +443,10 @@ def _pad_shards(data: FederatedDataset) -> tuple[jnp.ndarray, jnp.ndarray]:
 class SimPrograms:
     """Pure functions of one (init, apply, data, statics) binding.
 
-    ``round_step(state, rng, scenario) -> (state, metrics)`` advances one
-    D-FL round on the legacy pytree state; ``run_scenario(scenario) ->
-    metrics`` runs the full segment-native scan.  Both are jit/vmap-safe;
-    `run_scenario` is what `scenarios.run_grid` vmaps across a grid.
+    ``run_scenario(scenario) -> metrics`` runs the full segment-native
+    scan; it is jit/vmap-safe, and it is what `scenarios.run_grid` vmaps
+    across a grid (a one-scenario run is that grid at G = 1,
+    `scenarios.simulate`).
 
     Checkpointable scan API (DESIGN.md §13): ``init_scan(scenario)`` builds
     the segment-native scan state ``{"w": (N, L_local, K) rows, "key": key
@@ -451,7 +457,9 @@ class SimPrograms:
     chunks ``0..n_chunks-1`` and applies `chunk_metrics` to the stacked
     rows (see `repro.checkpoint.checkpoint.run_resumable`) replays the
     same per-chunk program whether or not it was interrupted in between —
-    that, not floating-point luck, is the bitwise-resume guarantee.
+    that, not floating-point luck, is the bitwise-resume guarantee.  The
+    host loop and the fused scan are two XLA programs of the same math:
+    they agree within float32 round-off, not bit for bit (DESIGN.md §16).
     Chunk c's train ``loss`` comes from chunk c+1's first local epoch;
     only the last chunk runs a train-loss pass of its own
     (``train_loss_passes``).
@@ -462,7 +470,6 @@ class SimPrograms:
     (`scenarios.GridRunner` and `checkpoint.run_resumable` do this).
     """
 
-    round_step: Callable[[dict, jax.Array, Scenario], tuple[dict, dict]]
     run_scenario: Callable[[Scenario], dict]
     n_clients: int
     n_rounds: int
@@ -507,7 +514,7 @@ def build_sim(
     training takes each client's pytree as exact layout views of its row
     (reshape/split/slice) every epoch, differentiates the loss on those
     leaves and lays the updated leaves back by one concatenation, so the
-    trained trajectory is bitwise what the pytree carry produced.
+    trained trajectory is the pytree carry's math on a segment layout.
 
     Args:
       init_fn: model init, `key -> params` pytree (one shared init; the
@@ -525,8 +532,8 @@ def build_sim(
         (must divide ``n_rounds``).  `run_scenario` metrics then carry a
         static ``(n_rounds // k,)`` leading axis for acc/loss — row j is
         round ``(j + 1) * k - 1`` — while ``bias`` stays per-round; grids
-        batch exactly as before.  ``k=1`` traces the EXACT per-round
-        program (bit-identity).
+        batch exactly as before.  ``k=1`` advances one round per chunk,
+        with no inner scan.
       track_bias: False skips the R&A ||Lambda||^2 diagnostic (bias is NaN
         for every round; its mask reductions leave the compiled hot loop).
       model_shards: Dm, the model-axis mesh size (static).  With
@@ -548,11 +555,11 @@ def build_sim(
         lr grid axis still batches; optimizer state is fresh each round
         (local Adam à la FedAvg: moments do not persist across exchange).
         ``sgd`` with momentum 0 is the same `p - lr*g` update expression
-        as the built-in GD path (tests pin bitwise equality).
+        as the built-in GD path (the same semantics, DESIGN.md §16).
 
     Returns:
-      `SimPrograms` with `round_step` / `run_scenario` / `init_scan` /
-      `advance_chunk` pure functions.
+      `SimPrograms` with `run_scenario` / `init_scan` / `advance_chunk`
+      pure functions.
     """
     from repro.core import aggregation
     from repro.optim import optimizers
@@ -772,8 +779,7 @@ def build_sim(
         closed loop's signal refresh; ``loss_in`` (N,) is every client's
         train loss on ``old_full``, from `local_train`'s first epoch.
         Both `_advance` and `_advance_closed` run THIS code, so the open-
-        and closed-loop paths cannot drift apart — the uniform policy's
-        bit-identity with the open loop rests on it.
+        and closed-loop paths share one round semantics.
 
         The codec (DESIGN.md §15) slots between training and delivery: it
         encodes the REPLICATED full rows (transmit mask + quantization
@@ -868,68 +874,14 @@ def build_sim(
         new_loc, trained, old_full, bias, loss_in = _round_core(
             w_loc, rng, scenario_t, mask, ratio_override
         )
-        out_full = _full_rows(new_loc)
-        # Signal refresh behind an optimization barrier: the extra
-        # reductions (per-client loss / update norms) must not give XLA
-        # new fusion opportunities inside the shared round math — the
-        # uniform policy's trajectory is REQUIRED to be bitwise identical
-        # to the open-loop path, and fusion-order changes break that at
-        # ~1e-7 (cf. the bias_sq_norm_fused note, DESIGN.md §9).
-        b_new, b_old, b_out = _fusion_barrier(
-            (trained, old_full, out_full)
-        )
-        upd = selection.update_norms(_views_batch(b_new), _views_batch(b_old))
+        upd = selection.update_norms(_views_batch(trained),
+                                     _views_batch(old_full))
         new_signals = selection.SelectionSignals(
-            loss=jnp.where(mask > 0, train_loss(b_out), signals.loss),
+            loss=jnp.where(mask > 0, train_loss(_full_rows(new_loc)),
+                           signals.loss),
             upd_norm=jnp.where(mask > 0, upd, signals.upd_norm),
         )
         return new_loc, new_signals, mask, bias, loss_in
-
-    def round_step(state: dict, rng: jax.Array, scenario: Scenario):
-        """One pure D-FL round: local training + traced-protocol exchange.
-
-        state: {"params": client-stacked pytree}; rng: this round's key.
-        This is the legacy pytree-state API: the pytree is segmented at
-        entry and reassembled at exit (`run_scenario` never does this —
-        its scan is segment-native).  ``scenario`` must be a per-round view
-        (rank-2 ``link_eps``; slice a dynamic scenario with
-        `Scenario.at_round` first).  A non-None ``participation`` mask
-        makes sampled-out clients skip local training, contribute nothing
-        to aggregation, and keep their parameters untouched.  Always
-        evaluates its metrics — `run_scenario` thins evaluation
-        (``eval_every``) by advancing without metrics between measure
-        points instead.
-        """
-        if jnp.ndim(scenario.link_eps) == 3:
-            raise ValueError(
-                "round_step takes a per-round scenario; slice a dynamic "
-                "scenario with scenario.at_round(t) (run_scenario does "
-                "this inside its scan)"
-            )
-        if scenario.policy_id is not None:
-            raise ValueError(
-                "round_step cannot run a closed-loop scenario: the "
-                "sampling policy needs the signal carry that only "
-                "run_scenario's scan threads (DESIGN.md §10)"
-            )
-        if model_shards != 1:
-            raise ValueError(
-                "round_step exposes the unsharded pytree-state API; build "
-                "the sim with model_shards=1 (run_scenario / advance_chunk "
-                "are the model-sharded entry points, DESIGN.md §13)"
-            )
-        part = scenario.participation
-        if part is not None:
-            part = part[:n]
-        w_seg, spec, mp = protocols._to_segments(state["params"], seg_len)
-        new_seg, _t, _o, bias, _l = _round_core(w_seg, rng, scenario, part)
-        with jax.named_scope(_EVAL_SCOPE):
-            metrics = {
-                "acc": evaluate(new_seg),
-                "loss": train_loss(new_seg),
-                "bias": bias,
-            }
-        return {"params": protocols._from_segments(new_seg, spec, mp)}, metrics
 
     # ------------------------------------------------------------------
     # The scan: ONE chunked structure for every scenario class.
@@ -1009,7 +961,6 @@ def build_sim(
         return chunk_metrics(rows)
 
     return SimPrograms(
-        round_step=_float32_matmuls(round_step),
         run_scenario=_float32_matmuls(run_scenario),
         n_clients=n,
         n_rounds=n_rounds,
@@ -1077,36 +1028,3 @@ def donate_kwargs() -> dict:
     dispatch), so this resolves to no-op kwargs there.
     """
     return {} if jax.default_backend() == "cpu" else {"donate_argnums": 0}
-
-
-def metrics_to_result(metrics: dict) -> SimResult:
-    return SimResult(
-        acc_per_client=np.asarray(metrics["acc"]),
-        loss_per_client=np.asarray(metrics["loss"]),
-        bias_norms=np.asarray(metrics["bias"]),
-    )
-
-
-def run(
-    init_fn: Callable[[jax.Array], Pytree],
-    apply_fn: Callable[[Pytree, jnp.ndarray], jnp.ndarray],
-    data: FederatedDataset,
-    net: topology.Network,
-    cfg: SimConfig,
-) -> SimResult:
-    """Scalar entry point: one scenario, one jitted scan (legacy API)."""
-    sim = build_sim(
-        init_fn, apply_fn, data,
-        seg_len=cfg.seg_len, local_epochs=cfg.local_epochs,
-        n_rounds=cfg.n_rounds, aayg_mixes=cfg.aayg_mixes,
-        agg_impl=cfg.agg_impl, eval_every=cfg.eval_every,
-        track_bias=cfg.track_bias, local_optimizer=cfg.local_optimizer,
-    )
-    metrics = jax.jit(sim.run_scenario, **donate_kwargs())(
-        make_scenario(net, cfg)
-    )
-    return metrics_to_result(metrics)
-
-
-# Alias: the scalar reference trajectory (see tests/test_scenarios.py).
-simulate = run

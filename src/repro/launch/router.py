@@ -31,9 +31,10 @@ protocol later).
     `_try_resolve` state machine, so a request that already delivered
     can never deliver twice — late results from a timed-out attempt, a
     hedge loser, or a replica that recovered mid-retry are discarded
-    (``router/results_discarded``).  Delivered results are bit-identical
-    to a direct `run_grid` regardless of which replica (or which
-    attempt) served them — replicas run the same pure programs.
+    (``router/results_discarded``).  Delivered results equal a direct
+    `run_grid`'s within float32 round-off regardless of which replica
+    (or which attempt) served them — replicas run the same pure
+    programs (DESIGN.md §16).
   * **Hedging** — with ``hedge_slack_frac`` set, a request whose
     deadline is nearly spent launches a second attempt on another
     replica; the first result wins the `_try_resolve` race.
@@ -63,9 +64,10 @@ attempt's error — because attempts are bounded (``max_attempts``), every
 attempt is bounded in time (``attempt_timeout_s``), and backoff delays
 are clipped to the request's remaining deadline.  The chaos tier
 (tests/test_router.py + tests/_serving_faults.py) kills, stalls, slows,
-and flaps replicas mid-run and asserts exactly this, plus bit-identity
-of every delivered result; benchmarks/serve_failover.py measures req/s
-and p99 before/during/after a replica kill.
+and flaps replicas mid-run and asserts exactly this, plus the
+equivalence of every delivered result with a direct run;
+benchmarks/serve_failover.py measures req/s and p99 before/during/after
+a replica kill.
 """
 from __future__ import annotations
 

@@ -9,17 +9,17 @@ Covers the traced exchange-codec layer end to end:
   * transmit-mask composition — `aggregation.apply_transmit_mask`
     semantics, the sparsity-aware Pallas kernel vs the jnp reference, and
     the all-ones mask as a bitwise no-op;
-  * the simulator path — codec=none (and topk at ratio 1) bitwise equal to
-    the codec-free program for EVERY protocol, quantization actually
-    perturbing the exchange, and non-participants never receiving encoded
-    state;
+  * the simulator path — codec=none (and topk at ratio 1) equal to the
+    codec-free program within float32 round-off for EVERY protocol,
+    quantization actually perturbing the exchange, and non-participants
+    never receiving encoded state;
   * the grid path — a `codecs=` axis sweeping ratio x protocol x PER in
-    one dispatch with a bitwise-neutral reference point, concat's neutral
-    fill, admission validation, and bit-identity on forced-8-device
-    ``('grid',)`` and 4x2 ``('grid', 'model')`` meshes (subprocess
-    selfcheck, mirrored by the CI sharding job);
+    one dispatch with a neutral reference point, concat's neutral fill,
+    admission validation, and the single-device semantics on
+    forced-8-device ``('grid',)`` and 4x2 ``('grid', 'model')`` meshes
+    (subprocess selfcheck, mirrored by the CI sharding job);
   * satellites — dtype-derived packet bits, the optimizer-zoo wiring
-    (momentum-0 SGD bitwise == plain GD), the joint budgeted
+    (momentum-0 SGD == plain GD in semantics), the joint budgeted
     selection+compression policy, and `Overhead.compressed`.
 """
 import os
@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _same import assert_same_semantics
 from repro.core import aggregation, compression, errors, overhead, routing, \
     selection, topology
 from repro.data import synthetic
@@ -50,19 +51,6 @@ def _toy_setup(n_clients=3):
     )
     init = lambda k: smallnets.init_mlp_clf(k, d_in=32, d_hidden=16)
     return data, net, init, smallnets.apply_mlp_clf
-
-
-def _assert_results_equal(a, b):
-    np.testing.assert_array_equal(a.acc, b.acc)
-    np.testing.assert_array_equal(a.loss, b.loss)
-    np.testing.assert_array_equal(a.bias, b.bias)
-
-
-def _assert_metrics_equal(a, b):
-    for k in a:
-        np.testing.assert_array_equal(
-            np.asarray(a[k]), np.asarray(b[k]), err_msg=k
-        )
 
 
 # ---------------------------------------------------------------------------
@@ -251,8 +239,8 @@ def test_pallas_tx_kernel_matches_jnp(mode):
     ("ideal_cfl", "ra_normalized"), ("none", "ra_normalized"),
 ])
 def test_codec_none_bitwise_neutral(protocol, mode):
-    """codec='none' (and topk at ratio 1) == the codec-free program,
-    bitwise, for every protocol."""
+    """codec='none' (and topk at ratio 1) == the codec-free program within
+    float32 round-off, for every protocol."""
     data, net, init, apply_fn = _toy_setup()
     cfg = simulator.SimConfig(seg_len=8, local_epochs=1, n_rounds=2,
                               protocol=protocol, mode=mode, seed=0)
@@ -260,10 +248,10 @@ def test_codec_none_bitwise_neutral(protocol, mode):
                               local_epochs=1, n_rounds=2)
     base = jax.jit(sim.run_scenario)(simulator.make_scenario(net, cfg))
     run = jax.jit(sim.run_scenario)
-    _assert_metrics_equal(base, run(simulator.make_scenario(
-        net, cfg, codec="none", compress_ratio=1.0)))
-    _assert_metrics_equal(base, run(simulator.make_scenario(
-        net, cfg, codec="topk", compress_ratio=1.0)))
+    assert_same_semantics(run(simulator.make_scenario(
+        net, cfg, codec="none", compress_ratio=1.0)), base)
+    assert_same_semantics(run(simulator.make_scenario(
+        net, cfg, codec="topk", compress_ratio=1.0)), base)
 
 
 def test_quant_codec_perturbs_exchange_but_not_locals():
@@ -282,10 +270,10 @@ def test_quant_codec_perturbs_exchange_but_not_locals():
     # the codec: local training operates on unencoded state.
     cfg_iso = simulator.SimConfig(seg_len=8, local_epochs=1, n_rounds=2,
                                   protocol="none", seed=0)
-    _assert_metrics_equal(
-        run(simulator.make_scenario(net, cfg_iso)),
+    assert_same_semantics(
         run(simulator.make_scenario(net, cfg_iso, codec="quant",
                                     compress_ratio=0.25)),
+        run(simulator.make_scenario(net, cfg_iso)),
     )
 
 
@@ -302,8 +290,8 @@ def test_nonparticipants_keep_unencoded_state():
     base = run(simulator.make_scenario(net, cfg, participation=mask))
     quant = run(simulator.make_scenario(net, cfg, participation=mask,
                                         codec="quant", compress_ratio=0.25))
-    np.testing.assert_array_equal(np.asarray(base["loss"])[:, 2],
-                                  np.asarray(quant["loss"])[:, 2])
+    assert_same_semantics(np.asarray(quant["loss"])[:, 2],
+                          np.asarray(base["loss"])[:, 2])
 
 
 # ---------------------------------------------------------------------------
@@ -327,7 +315,8 @@ def _codec_grids(net):
 
 def test_grid_codec_axis_neutral_point():
     """A ratio x protocol grid runs as one dispatch per (protocol, mode)
-    group and its neutral point == the codec-free grid, bitwise."""
+    group and its neutral point == the codec-free grid, within float32
+    round-off."""
     data, net, init, apply_fn = _toy_setup()
     base, grid = _codec_grids(net)
     cfg = simulator.SimConfig(n_rounds=2, local_epochs=1, seg_len=8)
@@ -337,17 +326,11 @@ def test_grid_codec_axis_neutral_point():
     res = runner.run(grid)
     assert len(res) == len(base) * 3
     for lbl in base.labels:
-        a, b = res_base.result(lbl), res.result(lbl + "/id")
-        np.testing.assert_array_equal(a.acc_per_client, b.acc_per_client)
-        np.testing.assert_array_equal(a.loss_per_client, b.loss_per_client)
-        np.testing.assert_array_equal(a.bias_norms, b.bias_norms)
-    # concat's neutral fill keeps codec-free rows bitwise intact.
+        assert_same_semantics(res.result(lbl + "/id"), res_base.result(lbl))
+    # concat's neutral fill keeps codec-free rows' semantics.
     res_cat = runner.run(scenarios.ScenarioGrid.concat(base, grid))
     for lbl in base.labels:
-        np.testing.assert_array_equal(
-            res_base.result(lbl).acc_per_client,
-            res_cat.result(lbl).acc_per_client,
-        )
+        assert_same_semantics(res_cat.result(lbl), res_base.result(lbl))
 
 
 def test_grid_codec_validation():
@@ -383,7 +366,7 @@ def test_grid_codec_validation():
 
 def _multi_device_check():
     """Codec grid on ('grid',) and 4x2 ('grid', 'model') meshes ==
-    single-device, bitwise (needs >= 8 devices)."""
+    single-device within float32 round-off (needs >= 8 devices)."""
     assert jax.device_count() >= 8, (
         f"needs 8 devices, have {jax.device_count()}"
     )
@@ -392,13 +375,14 @@ def _multi_device_check():
     cfg = simulator.SimConfig(n_rounds=2, local_epochs=1, seg_len=8)
     runner = scenarios.GridRunner(init, apply_fn, data, cfg)
     ref = runner.run(grid)
-    _assert_results_equal(ref, runner.run(grid, devices=jax.devices()[:8]))
+    assert_same_semantics(runner.run(grid, devices=jax.devices()[:8]), ref)
     mesh42 = launch_mesh.grid_model_mesh(8, model_shards=2)
-    _assert_results_equal(ref, runner.run(grid, sharding=mesh42))
+    assert_same_semantics(runner.run(grid, sharding=mesh42), ref)
 
 
 def test_codec_grid_sharded_matches_single_device():
-    """Forced 8-device sharded codec grids == single-device (bitwise)."""
+    """Forced 8-device sharded codec grids == single-device (within
+    float32 round-off)."""
     if jax.device_count() >= 8:
         _multi_device_check()
         return
@@ -435,8 +419,10 @@ def test_local_optimizer_sgd_is_bitwise_plain_gd():
                                   local_epochs=2, n_rounds=2,
                                   local_optimizer="sgd")
     sc = simulator.make_scenario(net, cfg)
-    _assert_metrics_equal(jax.jit(sim.run_scenario)(sc),
-                          jax.jit(sim_sgd.run_scenario)(sc))
+    # The same update expression in another program: equal within
+    # float32 round-off.
+    assert_same_semantics(jax.jit(sim_sgd.run_scenario)(sc),
+                          jax.jit(sim.run_scenario)(sc))
 
 
 def test_local_optimizer_adamw_changes_training():

@@ -1,7 +1,7 @@
 """Segment codec round-trip: golden + property tests (DESIGN.md §13).
 
 The segment-native refactor moved `protocols._to_segments` /
-`_from_segments` out of the per-round hot loop to the simulate()
+`_from_segments` out of the per-round hot loop to the run_scenario()
 boundary; these tests pin the codec contract that move relies on:
 
   * golden layout — flatten order is tree-flatten order, the final

@@ -11,9 +11,9 @@ import threading
 import time
 from concurrent.futures import CancelledError, wait
 
-import numpy as np
 import pytest
 
+from _same import assert_same_semantics
 from _serving_faults import install
 from repro.core import topology
 from repro.data import synthetic
@@ -59,19 +59,12 @@ def _grid(net, proto="ra", label="g", seed=0):
     )
 
 
-def _assert_same(got, want):
-    np.testing.assert_array_equal(np.asarray(got.acc), np.asarray(want.acc))
-    np.testing.assert_array_equal(np.asarray(got.loss),
-                                  np.asarray(want.loss))
-    assert np.array_equal(np.asarray(got.bias), np.asarray(want.bias),
-                          equal_nan=True)
-
-
 def test_poisoned_dispatch_fails_only_the_poisoned_request(toy):
     """Coalesced dispatch 0 raises: each member is retried INDIVIDUALLY
     (blast-radius shrink, DESIGN.md §12) — the request whose solo retry
-    also raises fails, its innocent neighbor is served, bit-identical to
-    a direct run; the next submit is served normally."""
+    also raises fails, its innocent neighbor is served with a direct
+    run's result (within float32 round-off); the next submit is served
+    normally."""
     data, nets, init, apply_fn = toy
     cfg = _cfg()
     boom = RuntimeError("injected dispatch failure")
@@ -92,9 +85,9 @@ def test_poisoned_dispatch_fails_only_the_poisoned_request(toy):
         fb = server.submit(_grid(nets[1], "ra", "b"))
         with pytest.raises(RuntimeError, match="injected"):
             fa.result(timeout=120)
-        _assert_same(fb.result(timeout=300), ref_b)
+        assert_same_semantics(fb.result(timeout=300), ref_b)
         fc = server.submit(_grid(nets[0], "ra", "c"))
-        _assert_same(fc.result(timeout=300), ref_c)
+        assert_same_semantics(fc.result(timeout=300), ref_c)
     assert probe.calls == 4
     assert probe.rows == [2, 1, 1, 1]
     snap = server.tracker.snapshot()
@@ -191,7 +184,7 @@ def test_stalled_dispatch_trips_deadlines_without_wedging(toy):
 def test_cancel_before_dispatch_reslices_coalesced_batch(toy):
     """Cancelling one request of a coalesced pending batch drops exactly
     its rows (ScenarioGrid.take re-slice); the surviving request's result
-    is bit-identical to a direct run."""
+    is a direct run's (within float32 round-off)."""
     data, nets, init, apply_fn = toy
     cfg = _cfg()
     ref = scenarios.run_grid(init, apply_fn, data,
@@ -211,7 +204,7 @@ def test_cancel_before_dispatch_reslices_coalesced_batch(toy):
         # THEN cancel: the drop must happen at dispatch time, by re-slice.
         time.sleep(0.35)
         assert f_cancel.cancel()      # still pending: cancel must win
-        _assert_same(f_keep.result(timeout=300), ref)
+        assert_same_semantics(f_keep.result(timeout=300), ref)
         assert fa.result(timeout=300) is not None
         with pytest.raises(CancelledError):
             f_cancel.result(timeout=1)
@@ -255,35 +248,42 @@ def test_submit_input_hardening(toy):
 
 def test_hard_stop_fails_all_pending_futures(toy):
     """stop(drain=False): queued, coalesced, and in-flight requests all
-    fail with ServerStopped immediately; new submits are rejected."""
+    fail with ServerStopped before stop returns — none is left pending,
+    and the in-flight dispatch's late result is discarded; new submits
+    are rejected."""
     data, nets, init, apply_fn = toy
     cfg = _cfg()
     server = serving.ScenarioServer(
         init, apply_fn, data, cfg,
         serve=serving.ServeConfig(max_batch=1, max_delay_s=0.01),
     )
-    install(server, stall_on={0: 1.0})
+    probe = install(server, stall_on={0: 1.0})
     server.start()
     f_inflight = server.submit(_grid(nets[0], "ra", "a"))
     time.sleep(0.2)                   # A is executing (stalled)
     f_queued = [server.submit(_grid(nets[0], "ra", f"q{i}"))
                 for i in range(3)]
-    t0 = time.monotonic()
+    pending = [f_inflight, *f_queued]
     server.stop(drain=False)
-    for f in [f_inflight, *f_queued]:
+    # Every future was failed by stop itself, not by a later dispatch.
+    assert all(f.done() for f in pending)
+    for f in pending:
         with pytest.raises(serving.ServerStopped):
-            f.result(timeout=1)
-    # Callers unblocked well before the stalled dispatch's 1s end.
-    assert time.monotonic() - t0 < 5.0
+            f.result(timeout=1)       # hang guard only: already done
+    # Only the stalled dispatch ever reached the runner; the queued
+    # requests were failed without running.
+    assert probe.calls == 1
     with pytest.raises(serving.ServerStopped):
         server.submit(_grid(nets[0], "ra", "late"))
     snap = server.tracker.snapshot()
     assert snap["serve/stopped_requests"] == 4
+    assert snap["serve/requests"] == 4
 
 
 def test_drain_stop_serves_everything_accepted(toy):
-    """stop(drain=True): every accepted request resolves with a result,
-    bit-identical to direct runs."""
+    """stop(drain=True): every accepted request resolves with a direct
+    run's result (coalesced batches of 2 against batches of 1: within
+    float32 round-off)."""
     data, nets, init, apply_fn = toy
     cfg = _cfg()
     reqs = [_grid(nets[i % 2], "ra", f"r{i}", seed=i) for i in range(4)]
@@ -297,7 +297,7 @@ def test_drain_stop_serves_everything_accepted(toy):
     server.stop()                     # drain default
     for f, ref in zip(futs, refs):
         assert f.done()
-        _assert_same(f.result(), ref)
+        assert_same_semantics(f.result(), ref)
     server.stop()                     # idempotent
 
 

@@ -1,13 +1,14 @@
 """Sharded grid execution (scenarios.run_grid devices=): equivalence tests.
 
-Three layers (DESIGN.md §7 determinism guarantees):
+Three layers (DESIGN.md §7, §16):
 
   * batch padding is pure bookkeeping — real rows untouched, filler rows
     routing-neutral (all nodes isolated), unpad drops them;
-  * a 1-device ('grid',) mesh through shard_map is bit-identical to the
-    plain jit(vmap) path;
-  * a multi-device mesh (8 forced host devices) is bit-identical to the
-    single-device path, covering the non-divisible pad (5 scenarios on 4
+  * a 1-device ('grid',) mesh through shard_map has the semantics of the
+    plain jit(vmap) path (a different program: equal within float32
+    round-off);
+  * a multi-device mesh (8 forced host devices) has the single-device
+    path's semantics, covering the non-divisible pad (5 scenarios on 4
     devices -> pad to 8) and the wider-than-batch mesh shrink (5
     scenarios, 8 devices -> 5-device mesh).  Runs
     in-process when the interpreter already has >= 8 devices (CI forces
@@ -27,6 +28,7 @@ import jax
 import numpy as np
 import pytest
 
+from _same import assert_same_semantics
 from repro.core import topology
 from repro.data import synthetic
 from repro.fl import scenarios, simulator
@@ -55,12 +57,6 @@ def _toy_grid(net, n_seeds=5):
     )
 
 
-def _assert_results_equal(a: scenarios.GridResult, b: scenarios.GridResult):
-    np.testing.assert_array_equal(a.acc, b.acc)
-    np.testing.assert_array_equal(a.loss, b.loss)
-    np.testing.assert_array_equal(a.bias, b.bias)
-
-
 def test_pad_scenario_batch_nondivisible():
     """5 scenarios padded to 8: real rows bit-equal, filler isolated."""
     _, net, _, _ = _toy_setup()
@@ -87,16 +83,17 @@ def test_pad_scenario_batch_nondivisible():
 
 
 def test_one_device_mesh_bit_identical():
-    """shard_map over a 1-device ('grid',) mesh == the plain vmap path,
-    through both the devices= and sharding= knobs."""
+    """shard_map over a 1-device ('grid',) mesh == the plain vmap path
+    within float32 round-off, through both the devices= and sharding=
+    knobs."""
     data, net, init, apply_fn = _toy_setup()
     grid = _toy_grid(net, n_seeds=3)
     cfg = simulator.SimConfig(n_rounds=2, local_epochs=1, seg_len=64)
     runner = scenarios.GridRunner(init, apply_fn, data, cfg)
     plain = runner.run(grid)
-    _assert_results_equal(plain, runner.run(grid, devices=1))
-    _assert_results_equal(
-        plain, runner.run(grid, sharding=launch_mesh.grid_mesh(1))
+    assert_same_semantics(runner.run(grid, devices=1), plain)
+    assert_same_semantics(
+        runner.run(grid, sharding=launch_mesh.grid_mesh(1)), plain
     )
 
 
@@ -113,7 +110,7 @@ def _multi_device_check():
     # 5 scenarios on 4 devices: pads to 8.  On 8 devices: the mesh is
     # wider than the batch and shrinks to 5 (no padding).
     for d in (4, 8):
-        _assert_results_equal(ref, runner.run(grid, devices=d))
+        assert_same_semantics(runner.run(grid, devices=d), ref)
     # Mixed-protocol grid: per-(protocol, mode) groups each pad their own
     # sub-batch (2 rows on 4 devices -> pad to 4).
     mixed = scenarios.ScenarioGrid.product(
@@ -122,13 +119,12 @@ def _multi_device_check():
                    ("cfl", "ra_normalized")],
         seeds=range(2),
     )
-    _assert_results_equal(
-        runner.run(mixed), runner.run(mixed, devices=4)
-    )
+    assert_same_semantics(runner.run(mixed, devices=4), runner.run(mixed))
 
 
 def test_multi_device_grid_matches_single_device():
-    """Forced 8-way host-device grid == single-device results (bitwise)."""
+    """Forced 8-way host-device grid == single-device results (within
+    float32 round-off)."""
     if jax.device_count() >= 8:
         _multi_device_check()
         return

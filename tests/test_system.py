@@ -11,7 +11,7 @@ import pytest
 
 from repro.core import topology
 from repro.data import synthetic
-from repro.fl import simulator
+from repro.fl import scenarios, simulator
 from repro.models import smallnets
 
 
@@ -31,7 +31,7 @@ def _run(fl_setup, protocol, mode="ra_normalized", rounds=12, **kw):
         protocol=protocol, mode=mode, n_rounds=rounds, local_epochs=3,
         seg_len=256, **kw,
     )
-    return simulator.run(init, apply_fn, data, net, cfg)
+    return scenarios.simulate(init, apply_fn, data, net, cfg)
 
 
 def test_ra_beats_aayg(fl_setup):
